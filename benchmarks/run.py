@@ -4,8 +4,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * fig4_*      — proxy<->area correlation runs (paper Fig. 4)
   * fig5_*      — best area per (benchmark, ET, method) (paper Fig. 5)
   * kernel rows — micro-benchmarks of the three kernels' workloads
-  * roofline_*  — per (arch x shape x mesh) ideal step time + bottleneck
-                  (from the dry-run artifacts, if present)
 """
 
 from __future__ import annotations
@@ -19,7 +17,7 @@ def main() -> None:
     budget = 30.0 if quick else 75.0
     rows: list[tuple[str, float, str]] = []
 
-    from . import fig4_proxy_area, fig5_area_vs_et, kernels_bench, roofline
+    from . import fig4_proxy_area, fig5_area_vs_et, kernels_bench
 
     for r in fig4_proxy_area.main(budget_s=budget):
         rows.append((
@@ -38,7 +36,6 @@ def main() -> None:
         ))
 
     kernels_bench.main(rows)
-    roofline.main(rows)
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

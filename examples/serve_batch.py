@@ -1,6 +1,6 @@
 """Batched serving example: greedy-decode a reduced model with KV caches —
-the serve-side counterpart of train_small.py (uses the real serve path
-that the decode_32k / long_500k dry-run cells lower).
+the serve-side counterpart of train_small.py (uses the real serve
+path).
 
     PYTHONPATH=src python examples/serve_batch.py --arch gemma3-1b
 """

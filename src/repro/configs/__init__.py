@@ -2,8 +2,8 @@
 
 Each module defines ``CONFIG`` (the exact published configuration) and
 ``REDUCED`` (a same-family miniature for CPU smoke tests).  The full
-configs are only ever *lowered* (ShapeDtypeStruct dry-runs); the reduced
-ones actually run.
+configs run on the chip (``chip_smoke.py`` serves ``qwen3-4b`` at its
+published widths); the reduced ones run in the CPU test suite.
 """
 
 from __future__ import annotations

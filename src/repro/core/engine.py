@@ -12,8 +12,8 @@ the re-verify-and-synthesize harvest.  Now they all speak one language:
 * :class:`SearchEngine` — the protocol: ``run(job) -> SearchOutcome``.
 * :class:`SearchOutcome` — the single report type: a list of
   exhaustively re-verified :class:`Candidate` netlists plus engine stats.
-  It also serves engine-agnostic consumers (the perf hillclimb wraps its
-  roofline records in one and queries :meth:`SearchOutcome.pareto`).
+  Its :meth:`SearchOutcome.pareto` and :meth:`SearchOutcome.min_by`
+  selectors also work over records of other types.
 * :func:`harvest` — the one shared instantiate → synthesize → exhaustive
   re-verify path.  Every candidate that reaches an outcome went through
   it; an unsound model raises :class:`UnsoundResultError` with enough
@@ -142,10 +142,10 @@ class Candidate:
 @dataclass
 class SearchOutcome:
     """The unified search report (replaces ``SearchReport`` /
-    ``TensorSearchReport`` / the hillclimb's ad-hoc record lists).
+    ``TensorSearchReport``).
 
     ``results`` usually holds :class:`Candidate`\\ s; engine-agnostic
-    consumers (the perf hillclimb) may hold other record types and use the
+    consumers may hold other record types and use the
     generic :meth:`pareto` / :meth:`min_by` selectors instead of
     :attr:`best`.
     """
@@ -300,8 +300,8 @@ class TensorEngine:
 class AnnealEngine:
     """Simulated annealing over shared-template parameters (numpy-only).
 
-    The hillclimb's accept-if-better loop, ported into the unified engine
-    with a temperature schedule and restarts: propose one literal/selector
+    An accept-if-better loop in the unified engine, with a temperature
+    schedule and restarts: propose one literal/selector
     mutation, score by the same proxy-area energy the tensor search uses
     (unsound candidates ranked by violation), accept per Metropolis.
     Needs neither z3 nor jax — the engine of last resort on bare images

@@ -29,7 +29,7 @@ from .circuits import Circuit, input_truth_tables
 from .engine import SearchOutcome, harvest
 from .templates import IGNORE, SharedTemplate, TemplateParams
 
-__all__ = ["tensor_search"]
+__all__ = ["population_scorer", "tensor_search"]
 
 
 def _proxy_score(lits: jax.Array, sel: jax.Array) -> jax.Array:
@@ -43,6 +43,27 @@ def _proxy_score(lits: jax.Array, sel: jax.Array) -> jax.Array:
     pit = used_prod.sum(axis=1)
     its = (sel > 0).sum(axis=2).max(axis=1)
     return 10.0 * pit + 2.0 * lit_cnt + 3.0 * its
+
+
+def population_scorer(in_tt: jax.Array, exact_vals: jax.Array,
+                      mesh: jax.sharding.Mesh | None = None):
+    """``(lits, sel) -> (wce, esum)`` for a candidate population.
+
+    With a ``mesh`` the population axis is split over its ``data`` axis
+    and each device scores its own shard: the compiler cannot partition a
+    kernel call, and left to itself would gather the whole population
+    onto every device.
+    """
+    def evaluate(lits, sel):
+        return ops.template_eval(lits, sel, in_tt, exact_vals)
+
+    if mesh is None:
+        return evaluate
+    from jax.sharding import PartitionSpec
+
+    # check_vma=False: a kernel call's outputs carry no varying-axes type
+    return jax.shard_map(evaluate, mesh=mesh, in_specs=PartitionSpec("data"),
+                         out_specs=PartitionSpec("data"), check_vma=False)
 
 
 def tensor_search(
@@ -73,6 +94,9 @@ def tensor_search(
     n, m = exact.n_inputs, exact.n_outputs
     T = pit if pit is not None else 2 * m
     tpl = SharedTemplate(n, m, pit=T)
+    in_tt = jnp.asarray(input_truth_tables(n))
+    exact_vals = jnp.asarray(exact.eval_words().astype(np.int32))
+    evaluate = population_scorer(in_tt, exact_vals, mesh)
     pop_sharding = None
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
@@ -81,8 +105,6 @@ def tensor_search(
         # population must tile evenly over the data axis; round up
         population += (-population) % n_shards
         pop_sharding = NamedSharding(mesh, PartitionSpec("data"))
-    in_tt = jnp.asarray(input_truth_tables(n))
-    exact_vals = jnp.asarray(exact.eval_words().astype(np.int32))
     key = jax.random.PRNGKey(seed)
     t0 = time.time()
 
@@ -90,7 +112,7 @@ def tensor_search(
 
     @jax.jit
     def fitness(lits, sel):
-        wce, esum = ops.template_eval(lits, sel, in_tt, exact_vals)
+        wce, esum = evaluate(lits, sel)
         sound = wce <= et
         score = _proxy_score(lits, sel)
         # unsound candidates are ranked by violation magnitude: the total

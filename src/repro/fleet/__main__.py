@@ -10,9 +10,11 @@ prints the five slowest jobs plus per-engine wall-time totals straight
 from the merged trace.  ``python -m repro.obs summary --trace <dir>``
 re-reads the same directory later.
 
-Exit status is non-zero when ``--min-new`` is set and the sweep added
-fewer operators than that (CI smoke gate); resumed no-op runs pass with
-``--min-new 0`` (the default).
+Exit status is non-zero when any job failed, or when ``--min-new`` is
+set and the sweep added fewer operators than that (CI smoke gate);
+resumed no-op runs pass with ``--min-new 0`` (the default).  Jobs on an
+engine the image cannot run (SMT without z3) are dropped before they
+start and do not fail the sweep.
 """
 
 from __future__ import annotations
@@ -151,6 +153,9 @@ def main(argv: list[str] | None = None) -> int:
         # workers' snapshots before the report reads the merged dir back
         dump_metrics(trace_dir, get_registry())
         trace_report(trace_dir, {j.key() for j in jobs})
+    if n_fail:
+        print(f"FAIL: {n_fail} job(s) failed", file=sys.stderr)
+        return 1
     if added < args.min_new:
         print(f"FAIL: added {added} < --min-new {args.min_new}", file=sys.stderr)
         return 1
@@ -158,4 +163,7 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from ..launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

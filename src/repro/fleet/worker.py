@@ -20,6 +20,7 @@ import hashlib
 import json
 import multiprocessing
 import os
+import sys
 import time
 import traceback
 from dataclasses import dataclass, field
@@ -50,6 +51,14 @@ class JobResult:
     engine_s: float = 0.0     # pure engine time (no receipt/commit IO)
     error: str | None = None
     stats: dict = field(default_factory=dict)   # engine stats (ok jobs)
+
+
+def _pin_to_cpu() -> None:
+    """Pool-child initializer: the chip belongs to the parent (tensor jobs
+    run there), so a child that reaches jax must never open a TPU."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:   # imported before this ran: set it directly
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
 
 
 def _flush_worker_obs() -> None:
@@ -130,7 +139,7 @@ def run_job(job: SearchJob, library_root: str | os.PathLike,
                 "engine_opts": opts,
                 "error": f"{type(exc).__name__}: {exc}",
                 "traceback": traceback.format_exc(limit=8),
-                "wall_s": round(time.time() - t0, 3),
+                "wall_s": round(time.time() - t0, 4),
             })
             _flush_worker_obs()
             return JobResult(job, "failed", wall_s=time.time() - t0,
@@ -148,7 +157,7 @@ def run_job(job: SearchJob, library_root: str | os.PathLike,
         "stats": outcome.stats,
         "engine_s": round(engine_s, 4),
         "commit_s": round(commit_s, 4),
-        "wall_s": round(time.time() - t0, 3),
+        "wall_s": round(time.time() - t0, 4),
     })
     _flush_worker_obs()
     return JobResult(job, "ok", n_results=len(outcome.results),
@@ -220,14 +229,13 @@ def run_sweep(spec, library_root: str | os.PathLike, *,
         # CPU engines are numpy/z3-only, so fork is cheap — but only while
         # jax (multithreaded) has not been imported into this process;
         # otherwise fall back to spawn to dodge the fork-with-threads trap.
-        import sys
-
         method = "fork" if "jax" not in sys.modules else "spawn"
         try:
             ctx = multiprocessing.get_context(method)
         except ValueError:  # pragma: no cover - non-POSIX fallback
             ctx = multiprocessing.get_context()
-        with ctx.Pool(min(workers, len(cpu_jobs))) as pool:
+        with ctx.Pool(min(workers, len(cpu_jobs)),
+                      initializer=_pin_to_cpu) as pool:
             results.extend(pool.map(worker, cpu_jobs))
     else:
         results.extend(worker(j) for j in cpu_jobs)

@@ -2,28 +2,29 @@
 multiplier netlists, MXU-native, at 4-bit and 8-bit operand widths.
 
 The obvious emulation of ``out[m,n] = Σ_k LUT[a[m,k], b[k,n]]`` is a gather
-per (m, k, n) — fast on a GPU's shared memory, slow on TPU.  The TPU-native
-rewrite (DESIGN.md §3) turns the LUT application into two dense
-contractions that run on the MXU:
+per (m, k, n) — fast on a GPU's shared memory, slow on TPU.  The TPU form
+splits the table by its column code ``y`` into 16 dense contractions that
+run on the MXU::
 
-1. ``R[m, k, y] = Σ_x onehot(a)[m, k, x] · LUT[x, y]``
-   — one (bm·bk, 16) x (16, 16) matmul: R row = the LUT row of ``a[m,k]``.
-2. ``out[m, n] = Σ_{k, y} R[m, k·16+y] · O[k·16+y, n]`` with
-   ``O[k·16+y, n] = [b[k,n] == y]``
-   — one (bm, bk·16) x (bk·16, bn) matmul.
+    out[m, n] = Σ_y Σ_k A_y[m, k] · B_y[k, n]
+    A_y[m, k] = LUT[a[m, k], y]      (16 VPU selects over the codes of a)
+    B_y[k, n] = [b[k, n] == y]       (one compare)
 
-**8-bit (W8A8) path.**  The same rewrite does not scale to 256 codes in
-one contraction: the one-hot operands and the ``R`` intermediate grow 16x
-(bm·bk·256 f32 alone overflows VMEM at useful block sizes).  But W8A8
-tables in this stack are *composed* — :mod:`repro.precision.compose`
-builds every 256x256 table as the exact shift-add of one 16x16 tile over
-operand nibbles::
+Every operand stays a 2-D (sublane, lane) tile of its block, so Mosaic
+never has to fold a sublane axis into lanes.  The table rides in SMEM and
+its entries are read as scalars.
+
+**8-bit (W8A8) path.**  The same split does not scale to 256 codes (256
+selects per column code, 256 contractions per block).  But W8A8 tables in
+this stack are *composed* — :mod:`repro.precision.compose` builds every
+256x256 table as the exact shift-add of one 16x16 tile over operand
+nibbles::
 
     LUT8[a, b] = T[al, bl] + (T[al, bh] + T[ah, bl]) << 4 + T[ah, bh] << 8
 
 so ``Σ_k LUT8[a, b]`` factors into **four 16x16-tile LUT matmuls combined
 by shift-add inside the kernel** — each over nibble planes of the codes,
-all sharing the one tile already resident in VMEM.  The wrapper recovers
+all sharing the one tile already resident in SMEM.  The wrapper recovers
 the tile from the (256, 256) table by exact integer inversion
 (:func:`repro.precision.compose.extract_tile`'s jnp twin below), keeping
 the public interface "codes + behaviour table" at every width — the
@@ -46,62 +47,56 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _lut16_contract(x: jax.Array, y: jax.Array, lut_f32: jax.Array
-                    ) -> jax.Array:
-    """``Σ_k LUT[x[m,k], y[k,n]]`` for 4-bit codes via the one-hot-twice
-    MXU form; shared by the 4-bit kernel (once) and the 8-bit kernel
-    (once per nibble-plane pair)."""
-    bm, bk = x.shape
-    bn = y.shape[1]
-    x_codes = jax.lax.broadcasted_iota(jnp.int32, (bm, bk, 16), 2)
-    x_oh = (x[:, :, None] == x_codes).astype(jnp.float32)
-    r = jax.lax.dot_general(
-        x_oh.reshape(bm * bk, 16),
-        lut_f32,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bm, bk * 16)
-    y_codes = jax.lax.broadcasted_iota(jnp.int32, (bk, 16, bn), 1)
-    y_oh = (y[:, None, :] == y_codes).astype(jnp.float32)
-    return jax.lax.dot_general(
-        r, y_oh.reshape(bk * 16, bn), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+def _lut16_contract(xs: list[jax.Array], ys: list[jax.Array], lut_ref
+                    ) -> list[list[jax.Array]]:
+    """``S[i][j][m, n] = Σ_k LUT[xs[i][m,k], ys[j][k,n]]`` for 4-bit code
+    planes, one MXU contraction per column code and plane pair; the 4-bit
+    kernel passes one plane each, the 8-bit kernel two nibble planes each,
+    so every plane's selects and compares are built once.  ``lut_ref`` is
+    the (16, 16) f32 table in SMEM."""
+    x_is = [[x == c for c in range(16)] for x in xs]
+    bm, bn = xs[0].shape[0], ys[0].shape[1]
+    acc = [[jnp.zeros((bm, bn), jnp.float32) for _ in ys] for _ in xs]
+    for col in range(16):
+        a_cols = []
+        for masks in x_is:
+            a_col = jnp.zeros(xs[0].shape, jnp.float32)
+            for c in range(16):
+                a_col = jnp.where(masks[c], lut_ref[c, col], a_col)
+            a_cols.append(a_col)
+        b_cols = [(y == col).astype(jnp.float32) for y in ys]
+        for i, a_col in enumerate(a_cols):
+            for j, b_col in enumerate(b_cols):
+                acc[i][j] += jax.lax.dot_general(
+                    a_col, b_col, (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+    return acc
 
 
-def _kernel(a_ref, b_ref, lut_ref, out_ref, *, bk: int, nk: int):
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
+def _kernel(a_ref, b_ref, lut_ref, out_ref):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = a_ref[...]          # (bm, bk) int32
-    b = b_ref[...]          # (bk, bn) int32
-    lut = lut_ref[...]      # (16, 16) int32
-    acc = _lut16_contract(a, b, lut.astype(jnp.float32))
+    [[acc]] = _lut16_contract([a_ref[...]], [b_ref[...]], lut_ref)
     out_ref[...] += acc.astype(jnp.int32)
 
 
-def _kernel8(a_ref, b_ref, tile_ref, out_ref, *, bk: int, nk: int):
+def _kernel8(a_ref, b_ref, tile_ref, out_ref):
     """Two-level 8-bit form: four nibble-plane tile matmuls + shift-add."""
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     a = a_ref[...]          # (bm, bk) int32 in [0, 256)
     b = b_ref[...]          # (bk, bn) int32 in [0, 256)
-    tile = tile_ref[...].astype(jnp.float32)    # (16, 16) generator tile
-    al, ah = a & 15, a >> 4
-    bl, bh = b & 15, b >> 4
-    s_ll = _lut16_contract(al, bl, tile)
-    s_lh = _lut16_contract(al, bh, tile)
-    s_hl = _lut16_contract(ah, bl, tile)
-    s_hh = _lut16_contract(ah, bh, tile)
+    (s_ll, s_lh), (s_hl, s_hh) = _lut16_contract(
+        [a & 15, a >> 4], [b & 15, b >> 4], tile_ref)
     # shift-add with f32-exact weights (partials < 2^24 per k-block)
     acc = s_ll + (s_lh + s_hl) * 16.0 + s_hh * 256.0
     out_ref[...] += acc.astype(jnp.int32)
@@ -164,17 +159,17 @@ def approx_matmul_pallas(
     grid = ((M + pm) // block_m, (N + pn) // block_n, (K + pk) // block_k)
 
     out = pl.pallas_call(
-        functools.partial(kernel, bk=block_k, nk=grid[2]),
+        kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((16, 16), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M + pm, N + pn), jnp.int32),
         interpret=interpret,
-    )(a, b, table)
+    )(a, b, table.astype(jnp.float32))
     out = out[:M, :N]
     if pk:  # remove the LUT[0,0] contribution of the K padding
         out = out - jnp.int32(pk) * lut[0, 0]

@@ -3,15 +3,22 @@
 This is the compute hot-spot of the beyond-paper *tensorized ALS search*
 (DESIGN.md §4): thousands of candidate parameter assignments are scored
 against the full input space per generation.  The ∀-inputs sweep is
-bit-packed — one ``uint32`` lane carries 32 input assignments — so a
+bit-packed — one 32-bit word carries 32 input assignments — so a
 candidate's products/sums are evaluated with word-wide VPU boolean ops, and
 the per-assignment integer re-interpretation (the miter's ``map``) is an
 unrolled shift/mask loop over the (static, <= 8) packed words.
 
+Layout: the population runs along lanes.  The wrapper transposes the
+candidates to ``lits`` (n, T, P) and ``sel`` (T, m, P), so every
+per-literal and per-product access in the kernel is a leading-axis ref
+slice of a 2-D (sublane, lane) tile; the packed truth tables are scalars
+in SMEM.  Words are handled as int32 bit patterns (logical shifts), and
+the outputs are (1, P) rows.
+
 Tiling: the grid runs over population blocks; each block holds the full
 (T, n, m, W) problem — for paper-scale operators (n <= 8, T <= 16, m <= 8,
 W <= 8) the per-block working set is a few hundred KB, far below VMEM.
-All loops over n / T / W are static (unrolled at trace time).
+All loops over n / T / m / W are static (unrolled at trace time).
 """
 
 from __future__ import annotations
@@ -20,20 +27,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-ALL_ONES = jnp.uint32(0xFFFFFFFF)
 USE, NEG = 0, 1
 
 
 def _kernel(
-    lits_ref,   # (Pb, T, n) int32
-    sel_ref,    # (Pb, m, T) int32
-    tt_ref,     # (n, W) uint32
-    ev_ref,     # (W * 32,) int32 (padded with zeros past S)
-    out_ref,    # (Pb,) int32 — worst-case error
-    sum_ref,    # (Pb,) int32 — total error over all assignments
+    lits_ref,   # (n, T, Pb) int32
+    sel_ref,    # (T, m, Pb) int32
+    tt_ref,     # (n, W) int32 bit patterns, SMEM
+    ev_ref,     # (W, 32, 1) int32 (padded with zeros past S)
+    wce_ref,    # (1, Pb) int32 — worst-case error
+    sum_ref,    # (1, Pb) int32 — total error over all assignments
     *,
     n: int,
     T: int,
@@ -41,48 +47,35 @@ def _kernel(
     W: int,
     S: int,
 ):
-    lits = lits_ref[...]
-    sel = sel_ref[...]
-    tt = tt_ref[...]
-    ev = ev_ref[...]
-    Pb = lits.shape[0]
-    ones = np.uint32(0xFFFFFFFF)  # inline literal; Pallas forbids captured arrays
-
-    # ---- products: AND over selected literals (bit-packed) -----------------
-    prods = jnp.zeros((Pb, T, W), dtype=jnp.uint32) | ones
-    for j in range(n):
-        ttj = tt[j]                                   # (W,)
-        litj = lits[:, :, j]                          # (Pb, T)
-        use = (litj == USE)[..., None]
-        neg = (litj == NEG)[..., None]
-        term = jnp.where(use, ttj[None, None, :], ones) & jnp.where(
-            neg, ~ttj[None, None, :], ones
-        )
-        prods = prods & term
-
-    # ---- sums: OR over selected products ------------------------------------
-    outs = jnp.zeros((Pb, m, W), dtype=jnp.uint32)
-    for t in range(T):
-        s = (sel[:, :, t] > 0)[..., None]             # (Pb, m, 1)
-        outs = outs | jnp.where(s, prods[:, t][:, None, :], np.uint32(0))
-
-    # ---- map + dist: per-assignment value, worst-case |err| ----------------
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (Pb, m, 32), 2)
-    weights = jnp.int32(1) << jax.lax.broadcasted_iota(jnp.int32, (Pb, m, 32), 1)
-    wce = jnp.zeros((Pb,), dtype=jnp.int32)
-    esum = jnp.zeros((Pb,), dtype=jnp.int32)
+    Pb = lits_ref.shape[-1]
+    use = [lits_ref[j] == USE for j in range(n)]      # (T, Pb) each
+    neg = [lits_ref[j] == NEG for j in range(n)]
+    sel = [sel_ref[t] > 0 for t in range(T)]          # (m, Pb) each
+    bit = jax.lax.broadcasted_iota(jnp.int32, (32, Pb), 0)
+    wce = jnp.zeros((1, Pb), jnp.int32)
+    esum = jnp.zeros((1, Pb), jnp.int32)
     for w in range(W):
-        word = outs[:, :, w]                          # (Pb, m) uint32
-        bits = ((word[..., None] >> shifts) & np.uint32(1)).astype(jnp.int32)
-        vals = (bits * weights).sum(axis=1)           # (Pb, 32)
-        err = jnp.abs(vals - ev[None, 32 * w : 32 * (w + 1)])
-        # mask lanes past the real input-space size S
-        lane = 32 * w + jax.lax.broadcasted_iota(jnp.int32, (Pb, 32), 1)
-        valid = (lane < S).astype(jnp.int32)
-        err = err * valid
-        wce = jnp.maximum(wce, err.max(axis=1))
-        esum = esum + err.sum(axis=1)
-    out_ref[...] = wce
+        # ---- products: AND over selected literals (bit-packed) -------------
+        prods = jnp.full((T, Pb), -1, jnp.int32)
+        for j in range(n):
+            word = tt_ref[j, w]
+            prods = (prods & jnp.where(use[j], word, -1)
+                     & jnp.where(neg[j], ~word, -1))
+        # ---- sums: OR over selected products --------------------------------
+        outs = jnp.zeros((m, Pb), jnp.int32)
+        for t in range(T):
+            outs = outs | jnp.where(sel[t], prods[t:t + 1, :], 0)
+        # ---- map + dist: per-assignment value, worst-case |err| ------------
+        vals = jnp.zeros((32, Pb), jnp.int32)
+        for i in range(m):
+            row = jnp.broadcast_to(outs[i:i + 1, :], (32, Pb))
+            vals = vals + ((jax.lax.shift_right_logical(row, bit) & 1) << i)
+        err = jnp.abs(vals - ev_ref[w])
+        # mask assignments past the real input-space size S
+        err = jnp.where(32 * w + bit < S, err, 0)
+        wce = jnp.maximum(wce, err.max(axis=0, keepdims=True))
+        esum = esum + err.sum(axis=0, keepdims=True)
+    wce_ref[...] = wce
     sum_ref[...] = esum
 
 
@@ -102,28 +95,28 @@ def template_eval_pallas(
     S = exact_vals.shape[0]
 
     pad = (-P) % block_p
-    if pad:
-        lits = jnp.pad(lits, ((0, pad), (0, 0), (0, 0)))
-        sel = jnp.pad(sel, ((0, pad), (0, 0), (0, 0)))
+    lits_t = jnp.pad(lits.transpose(2, 1, 0), ((0, 0), (0, 0), (0, pad)))
+    sel_t = jnp.pad(sel.transpose(2, 1, 0), ((0, 0), (0, 0), (0, pad)))
+    tt = jax.lax.bitcast_convert_type(in_tt, jnp.int32)
     ev = jnp.pad(exact_vals.astype(jnp.int32), (0, W * 32 - S))
 
     wce, esum = pl.pallas_call(
         functools.partial(_kernel, n=n, T=T, m=m, W=W, S=S),
         grid=((P + pad) // block_p,),
         in_specs=[
-            pl.BlockSpec((block_p, T, n), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_p, m, T), lambda i: (i, 0, 0)),
-            pl.BlockSpec((n, W), lambda i: (0, 0)),
-            pl.BlockSpec((W * 32,), lambda i: (0,)),
+            pl.BlockSpec((n, T, block_p), lambda i: (0, 0, i)),
+            pl.BlockSpec((T, m, block_p), lambda i: (0, 0, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((W, 32, 1), lambda i: (0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_p,), lambda i: (i,)),
-            pl.BlockSpec((block_p,), lambda i: (i,)),
+            pl.BlockSpec((1, block_p), lambda i: (0, i)),
+            pl.BlockSpec((1, block_p), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(((P + pad),), jnp.int32),
-            jax.ShapeDtypeStruct(((P + pad),), jnp.int32),
+            jax.ShapeDtypeStruct((1, P + pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, P + pad), jnp.int32),
         ],
         interpret=interpret,
-    )(lits, sel, in_tt, ev)
-    return wce[:P], esum[:P]
+    )(lits_t, sel_t, tt, ev.reshape(W, 32, 1))
+    return wce[0, :P], esum[0, :P]

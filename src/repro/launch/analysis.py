@@ -1,23 +1,17 @@
-"""Compiled-artifact analysis: roofline terms from the dry-run.
+"""Compiled-artifact and plan reports.
 
-``compiled.cost_analysis()`` gives HLO FLOPs and bytes accessed;
-collective bytes are *not* in cost_analysis, so we parse the optimized
-HLO text and sum wire bytes of every collective op, using ring-algorithm
-wire factors with the participant count taken from ``replica_groups``.
-
-Terms (per step, whole mesh -> seconds):
-
-    compute    = HLO_FLOPs / (chips * peak)
-    memory     = HLO_bytes / (chips * hbm_bw)
-    collective = wire_bytes / (chips * ici_bw)
+:func:`collective_stats` parses optimized HLO text and sums the wire
+bytes of every collective op (``compiled.cost_analysis()`` leaves them
+out), using ring-algorithm wire factors with the participant count taken
+from ``replica_groups``.  :func:`plan_report` and
+:func:`sensitivity_report` render a QoS plan and a measured sensitivity
+profile as text tables.
 """
 
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-
-from . import mesh as hw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -110,113 +104,6 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
     return stats
 
 
-@dataclass
-class Roofline:
-    """All HLO-derived quantities are PER DEVICE (jax's cost_analysis on an
-    SPMD module reports the per-partition program); ``model_flops`` is the
-    GLOBAL analytic 6·N·D / 2·N·D."""
-
-    arch: str
-    shape: str
-    mesh: str
-    chips: int
-    hlo_flops: float        # per device
-    hlo_bytes: float        # per device
-    wire_bytes: float       # per device (ring wire bytes)
-    model_flops: float      # global
-    bytes_per_device: float | None
-    collectives: dict[str, int]
-    model_bytes: float = 0.0  # global minimum HBM traffic (decode: weights+cache)
-
-    @property
-    def t_compute(self) -> float:
-        return self.hlo_flops / hw.PEAK_FLOPS_BF16
-
-    @property
-    def t_memory(self) -> float:
-        return self.hlo_bytes / hw.HBM_BW
-
-    @property
-    def t_collective(self) -> float:
-        return self.wire_bytes / hw.ICI_BW_PER_LINK
-
-    @property
-    def bottleneck(self) -> str:
-        terms = {
-            "compute": self.t_compute,
-            "memory": self.t_memory,
-            "collective": self.t_collective,
-        }
-        return max(terms, key=terms.get)
-
-    @property
-    def useful_flops_ratio(self) -> float:
-        total_hlo = self.hlo_flops * self.chips
-        return self.model_flops / total_hlo if total_hlo else 0.0
-
-    @property
-    def t_star(self) -> float:
-        """Ideal step time: useful FLOPs at peak, or (for bandwidth-bound
-        steps like decode) the unavoidable HBM traffic at full bandwidth —
-        whichever bound is tighter."""
-        return max(
-            self.model_flops / (self.chips * hw.PEAK_FLOPS_BF16),
-            self.model_bytes / (self.chips * hw.HBM_BW),
-        )
-
-    @property
-    def roofline_fraction(self) -> float:
-        """ideal step time / modelled step time (max-of-terms = perfect
-        overlap; the sum-of-terms pessimistic variant is in EXPERIMENTS)."""
-        t_step = max(self.t_compute, self.t_memory, self.t_collective)
-        return self.t_star / t_step if t_step else 0.0
-
-    def as_dict(self) -> dict:
-        return {
-            "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips, "hlo_flops": self.hlo_flops,
-            "hlo_bytes": self.hlo_bytes, "wire_bytes": self.wire_bytes,
-            "model_flops": self.model_flops,
-            "bytes_per_device": self.bytes_per_device,
-            "model_bytes": self.model_bytes,
-            "t_compute": self.t_compute, "t_memory": self.t_memory,
-            "t_collective": self.t_collective, "t_star": self.t_star,
-            "bottleneck": self.bottleneck,
-            "useful_flops_ratio": self.useful_flops_ratio,
-            "roofline_fraction": self.roofline_fraction,
-            "collectives": self.collectives,
-        }
-
-
-def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
-            model_flops: float) -> Roofline:
-    cost = compiled.cost_analysis() or {}
-    flops = float(cost.get("flops", 0.0))
-    byts = float(cost.get("bytes accessed", 0.0))
-    stats = collective_stats(compiled.as_text())
-    mem = None
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            mem = float(
-                getattr(ma, "temp_size_in_bytes", 0)
-                + getattr(ma, "argument_size_in_bytes", 0)
-                + getattr(ma, "output_size_in_bytes", 0)
-                - getattr(ma, "alias_size_in_bytes", 0)
-            )
-    except Exception:
-        mem = None
-    return Roofline(
-        arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-        hlo_flops=flops, hlo_bytes=byts, wire_bytes=stats.wire_bytes_total,
-        model_flops=model_flops, bytes_per_device=mem,
-        collectives=stats.counts,
-    )
-
-
-# ---------------------------------------------------------------------------
-# QoS layer-plan reporting: which approximate operator each layer runs on
-# ---------------------------------------------------------------------------
 def plan_report(plan) -> str:
     """Human-readable per-layer operator table for a QoS
     :class:`~repro.library.qos.LayerPlan` — operator key, area vs the exact
@@ -270,11 +157,3 @@ def sensitivity_report(profile) -> str:
                if b in profile.costs else "")
         )
     return "\n".join(lines)
-
-
-def model_flops_train(n_active_params: int, tokens: int) -> float:
-    return 6.0 * n_active_params * tokens
-
-
-def model_flops_decode(n_active_params: int, tokens: int) -> float:
-    return 2.0 * n_active_params * tokens

@@ -1,39 +1,22 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A *function*, not a module-level constant — importing this module never
-touches jax device state (the dry-run sets XLA_FLAGS before first init).
-
-Single pod: (data=16, model=16) = 256 chips (TPU v5e pod).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the ``pod`` axis is
-outer data-parallelism (batch shards over pod x data via the 'batch'
-logical rule), so cross-pod traffic is gradient all-reduce only — the
-layout that survives slow inter-pod links.
+Functions, not module-level constants: importing this module never
+touches jax device state.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; older jax defaults to Auto anyway
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # pragma: no cover - exercised on older jax images
-
-    def _axis_kw(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+def _axis_kw(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_smoke_mesh():
-    """1-device mesh with the production axis names (CPU tests)."""
+    """(1, n) mesh over the local devices with the serving/training axis
+    names ``data`` and ``model``."""
     n = jax.device_count()
     return jax.make_mesh((1, n), ("data", "model"), **_axis_kw(2))
 
@@ -46,9 +29,3 @@ def make_fleet_mesh():
     per-generation elite selection is the only cross-device collective.
     """
     return jax.make_mesh((jax.device_count(),), ("data",), **_axis_kw(1))
-
-
-# TPU v5e hardware constants (per chip) — the roofline denominators.
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW_PER_LINK = 50e9         # bytes/s/link (~45-50 GB/s on v5e)

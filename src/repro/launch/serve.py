@@ -1,7 +1,7 @@
 """Serving launcher: a thin CLI over :mod:`repro.serving`.
 
-CPU-runnable with ``--reduced``; the same decode step is what the dry-run
-lowers for the decode_32k / long_500k cells on the production mesh.
+CPU-runnable with ``--reduced``; at published widths it serves on the
+chip (``chip_smoke.py`` drives the same construction for Qwen3-4B).
 Requests are synthetic prompts on a deterministic load profile
 (steady / ramp / spike); decoding is greedy.  Prefill and decode
 throughput are reported *separately* — prefill here is a python-loop over
@@ -84,7 +84,11 @@ from ..serving.loadgen import PROFILES
 from .mesh import make_smoke_mesh
 
 
-def _frontier(library: str, width):
+DEFAULT_QOS_BUDGET = 50.0   # startup budget in summed mae16 units
+
+
+def library_frontier(library: str, width):
+    """The library's frontier at ``width``; exits when it has none."""
     from ..precision.plans import load_frontier
 
     try:
@@ -93,7 +97,8 @@ def _frontier(library: str, width):
         raise SystemExit(str(e))
 
 
-def _startup_plan(cfg, compiled, exact_area, budget: float, sens=None):
+def startup_plan(cfg, compiled, exact_area,
+                 budget: float = DEFAULT_QOS_BUDGET, sens=None):
     """The one-shot selection: uniform sensitivities (mae16-unit budget)
     unless a measured ``--profile`` cost model is at hand."""
     from ..library import select_plan
@@ -365,7 +370,7 @@ def main() -> None:
         else:
             width = select_width(cfg, requested=args.width)
             cfg = cfg.with_approx_mlp(bits=width.bits)
-            compiled, exact_area, bits = _frontier(args.library, width)
+            compiled, exact_area, bits = library_frontier(args.library, width)
             sens = (costs_for(profile_obj, width.bits, compiled,
                               cfg.n_layers)
                     if profile_obj is not None else None)
@@ -385,10 +390,12 @@ def main() -> None:
                         "--profile prices the startup plan in measured-"
                         "drift units; give an explicit --qos-budget in "
                         "mean-|Δlogit| terms (the mae16-scaled default "
-                        "of 50.0 would max-downgrade every layer)")
-                plan = _startup_plan(
+                        f"of {DEFAULT_QOS_BUDGET} would max-downgrade every "
+                        "layer)")
+                plan = startup_plan(
                     cfg, compiled, exact_area,
-                    50.0 if args.qos_budget is None else args.qos_budget,
+                    DEFAULT_QOS_BUDGET if args.qos_budget is None
+                    else args.qos_budget,
                     sens=sens)
             if args.watch_library:
                 # non-native widths pin the watcher to the composed
@@ -475,7 +482,9 @@ def main() -> None:
                          "families only (paged decode)")
 
     with parallel.activate(mesh), mesh:
-        params = init_model(cfg, key)
+        # one program: eager init draws each weight in f32 for all layers
+        # before casting it, more than a full-width model leaves free
+        params = jax.jit(init_model, static_argnums=0)(cfg, key)
         warmup = None
         if cfg.family == "audio":
             from ..models.encdec import prefill_cross
@@ -795,4 +804,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

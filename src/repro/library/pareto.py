@@ -2,7 +2,7 @@
 
 Dominance is in the minimization sense over a tuple of objectives (for
 operators: synthesized area and measured error).  :func:`pareto_front` is
-generic — the perf hillclimb uses it over roofline terms — while
+generic — any record type with any objectives — while
 :class:`ParetoFrontier` wraps the operator-specific area-vs-error queries
 that replace the per-script ``report.best`` idiom.
 """

@@ -71,8 +71,10 @@ def _init_layer(cfg: ModelConfig, key) -> Params:
 def init_lm(cfg: ModelConfig, key) -> Params:
     ks = list(jax.random.split(key, cfg.n_layers + 3))
     dt = cfg.jnp_dtype
-    per_layer = [_init_layer(cfg, ks[i]) for i in range(cfg.n_layers)]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+    # one batched init over the layer keys: the same values as a loop over
+    # layers, but a jitted full-width init stays one layer's program
+    stacked = jax.vmap(lambda k: _init_layer(cfg, k))(
+        jnp.stack(ks[:cfg.n_layers]))
     params: Params = {
         "embed": L._dense_init(ks[-1], (cfg.vocab_size, cfg.d_model), dt, fan_in=cfg.d_model),
         "layers": stacked,
@@ -134,8 +136,8 @@ def forward_lm(
     (n_layers, side, side) stack (a QoS
     :class:`~repro.library.qos.LayerPlan`), which rides through the layer
     scan alongside the stacked params; side = 16 (W4A4) or 256 (W8A8).
-    ``scan_unroll``: unroll the layer scan — used by the roofline analysis
-    (XLA cost_analysis counts a rolled scan body once; see dryrun.py).
+    ``scan_unroll``: unroll the layer scan, so that XLA cost_analysis
+    (which counts a rolled scan body once) sees every layer.
     """
     tokens = batch["tokens"]
     x = params["embed"][tokens].astype(cfg.jnp_dtype)
@@ -276,7 +278,10 @@ def init_paged_caches(cfg: ModelConfig, batch: int, n_pages: int,
                 c["vp"] = jnp.zeros((n_pages + 1, page_size,
                                      cfg.n_kv_heads, cfg.hd), dt)
         caches.append(c)
-    return caches
+    # on the active mesh from the start: the step's outputs carry the mesh
+    # in their type, so unplaced first inputs would trace the step twice
+    return [{name: shard(x, *(None,) * x.ndim) for name, x in c.items()}
+            for c in caches]
 
 
 def shard_decode_caches(caches: list[Params], cfg: ModelConfig) -> list[Params]:

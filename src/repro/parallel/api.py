@@ -6,7 +6,7 @@ mesh axes under the active mesh, with a divisibility guard: a logical axis
 whose dimension does not divide by its mesh extent falls back to
 replication instead of producing uneven shards (e.g. whisper's prime-ish
 vocab).  Outside any context every annotation is a no-op, so the same
-model code runs single-device tests and 512-chip dry-runs unchanged.
+model code runs single-device tests and multi-chip meshes unchanged.
 """
 
 from __future__ import annotations

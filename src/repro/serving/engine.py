@@ -770,6 +770,7 @@ class ContinuousServingEngine(ServingEngine):
         super().__init__(cfg, params, batch=max_slots, prompt_len=prompt_len,
                          gen_len=gen_len, **kw)
         self._started = False
+        self._last_step_args = None   # what step_once last passed the step
 
     def _make_step_fn(self):
         from ..models import decode_paged_fn
@@ -789,6 +790,19 @@ class ContinuousServingEngine(ServingEngine):
                 self._trace_count += 1
                 return pstep(cfg, params, caches, tok, pos, active, tables)
         return step_fn
+
+    def lowered_step(self):
+        """The decode step lowered (not compiled) at the types of the
+        arguments :meth:`step_once` last passed it: the program the device
+        ran, for checks of which kernels it holds.  Reuses that trace."""
+        assert self._last_step_args is not None, \
+            "run a step before lowered_step()"
+        # the caches were donated to the step, so only their types remain
+        types = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            self._last_step_args)
+        return self._jit_step.lower(*types)
 
     # ----------------------------------------------------------------- state
     @property
@@ -1166,12 +1180,9 @@ class ContinuousServingEngine(ServingEngine):
             # injected latency spike is indistinguishable from a real one
             # to the telemetry, the SLO monitors and the detectors
             time.sleep(self.inject_step_delay)
-        if self._adaptive:
-            logits, self._caches = self._jit_step(
-                self.params, self._caches, *jt, luts)
-        else:
-            logits, self._caches = self._jit_step(
-                self.params, self._caches, *jt)
+        self._last_step_args = ((self.params, self._caches, *jt)
+                                + ((luts,) if self._adaptive else ()))
+        logits, self._caches = self._jit_step(*self._last_step_args)
         logits.block_until_ready()
         step_s = time.perf_counter() - t0
 

@@ -1,6 +1,6 @@
 """Jit-compiled train / prefill / decode step builders.
 
-``make_train_step`` is what both the launcher and the dry-run lower:
+``make_train_step`` is what the launcher jits:
 value_and_grad over the family loss, optional microbatch gradient
 accumulation (a ``lax.scan`` over microbatches — decouples global batch
 from per-device memory), then the AdamW update.  All functions are pure;
@@ -52,8 +52,8 @@ def make_train_step(
             batch,
         )
         zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        # scan_unroll: the roofline harness must see every microbatch's ops
-        # (XLA cost_analysis counts a rolled scan body once)
+        # scan_unroll: every microbatch's ops visible to XLA cost_analysis
+        # (which counts a rolled scan body once)
         (total_loss, total_grads), _ = jax.lax.scan(
             micro, (jnp.float32(0.0), zero_grads), split,
             unroll=True if scan_unroll else 1,

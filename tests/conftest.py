@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches see
-the real single CPU device; only launch/dryrun.py forces 512 devices."""
+the real single CPU device."""
 
 import numpy as np
 import pytest

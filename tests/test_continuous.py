@@ -361,6 +361,31 @@ def test_continuous_completes_all_trace_pinned(approx_setup):
         assert np.array_equal(gen, eng2.completions[rid]), rid
 
 
+def test_continuous_under_serving_mesh_traces_once(approx_setup):
+    """The serve CLI runs the engine under its mesh: the page pools must
+    start on that mesh, or the step's first outputs differ in type from
+    its first inputs and the step traces twice."""
+    from repro import parallel
+    from repro.launch.mesh import make_smoke_mesh
+
+    _, _, compiled, exact_area, cfg, params, ladder = approx_setup
+    mesh = make_smoke_mesh()
+    with parallel.activate(mesh), mesh:
+        eng, _ = _run_plain(cfg, params, compiled, exact_area, ladder,
+                            profile=_profile(ticks=2))
+    assert eng.trace_count == 1
+
+
+def test_lowered_step_reuses_the_served_trace(approx_setup):
+    _, _, compiled, exact_area, cfg, params, ladder = approx_setup
+    eng, _ = _run_plain(cfg, params, compiled, exact_area, ladder,
+                        profile=_profile(ticks=1))
+    text = eng.lowered_step().as_text()
+    assert eng.trace_count == 1
+    # the per-layer LUT stack is an argument of the lowered step
+    assert f"tensor<{cfg.n_layers}x16x16xi32>" in text
+
+
 def test_out_of_pages_blocks_admission_never_corrupts(approx_setup):
     _, _, compiled, exact_area, cfg, params, ladder = approx_setup
     # pool holds exactly one in-flight request's pages (4 of them) plus

@@ -243,3 +243,42 @@ def test_fleet_cli_reports_densification(tmp_path, capsys):
     assert rc == 0
     assert "frontier densification" in out
     assert "adder2b_wce2" in out
+
+
+def _cli_spec(tmp_path, engines):
+    spec_file = tmp_path / "spec.json"
+    spec_file.write_text(json.dumps({
+        "name": "cli-fail", "benchmarks": ["adder"], "bits": [2],
+        "ets": [2], "engines": engines, "budget_s": 20.0,
+        "engine_opts": {"anneal": {"steps": 500, "restarts": 1,
+                                   "keep": 1}},
+    }))
+    return ["--library", str(tmp_path / "lib"), "--sweep", str(spec_file),
+            "--workers", "0"]
+
+
+def test_fleet_cli_exits_nonzero_when_a_job_fails(tmp_path, monkeypatch,
+                                                   capsys):
+    """A job that raises (say, a kernel the chip's compiler refuses) is
+    receipted as failed, and the sweep's exit status says so."""
+    from repro.fleet import worker
+    from repro.fleet.__main__ import main
+
+    class Refused:
+        def run(self, job):
+            raise RuntimeError("kernel refused")
+
+    monkeypatch.setattr(worker, "get_engine", lambda name, **kw: Refused())
+    assert main(_cli_spec(tmp_path, ["anneal"])) == 1
+    assert "1 job(s) failed" in capsys.readouterr().err
+
+
+def test_fleet_cli_dropped_engine_is_not_a_failure(tmp_path, capsys):
+    from repro.core.miter import HAVE_Z3
+    from repro.fleet.__main__ import main
+
+    if HAVE_Z3:
+        pytest.skip("needs a z3-less image, where the SMT engine is dropped")
+    assert main(_cli_spec(tmp_path, ["shared", "anneal"])) == 0
+    assert "skipping 1 job(s) on unavailable engines" in \
+        capsys.readouterr().out

@@ -1,0 +1,113 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip at real widths.
+
+Interpret mode accepts kernels that the chip's compiler (Mosaic) refuses,
+so each kernel is compiled here for a *described* chip — one of a
+``v5e:2x2`` topology, no device attached — at the shapes it runs at:
+the LUT matmul at the Qwen3-4B decode MLP shapes, flash attention at one
+32-head 512-token block, and ``template_eval`` at a paper-scale
+population, alone and split over the four chips the fleet search shards
+it across.  A kernel that reached the chip appears in the compiled
+program as a ``tpu_custom_call``.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from repro.core.arith import benchmark
+from repro.core.circuits import input_truth_tables
+from repro.kernels.approx_matmul import approx_matmul_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.template_eval import template_eval_pallas
+
+D_MODEL, D_FF, DECODE_M = 2560, 9728, 8   # Qwen3-4B MLP, decode rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("side", [16, 256], ids=["w4", "w8"])
+@pytest.mark.parametrize("K,N", [(D_MODEL, D_FF), (D_FF, D_MODEL)],
+                         ids=["up_gate", "down"])
+def test_approx_matmul_compiles_at_qwen3_mlp_shapes(one_chip, side, K, N):
+    a, b, lut = (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+                 for shape in ((DECODE_M, K), (K, N), (side, side)))
+    text = _compiled_text(approx_matmul_pallas, a, b, lut)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles(one_chip):
+    q = jax.ShapeDtypeStruct((1, 32, 512, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compiled_text(flash_attention_pallas, q, q, q)
+    assert "tpu_custom_call" in text
+
+
+def _population(P, T, n, m, sharding):
+    return (jax.ShapeDtypeStruct((P, T, n), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((P, m, T), jnp.int32, sharding=sharding))
+
+
+def test_template_eval_compiles_at_paper_scale(one_chip):
+    P, T, n, m, W = 4096, 16, 8, 8, 8
+    lits, sel = _population(P, T, n, m, one_chip)
+    text = _compiled_text(
+        template_eval_pallas, lits, sel,
+        jax.ShapeDtypeStruct((n, W), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((32 * W,), jnp.int32, sharding=one_chip))
+    assert "tpu_custom_call" in text
+
+
+def test_sharded_template_eval_compiles_on_four_chips(topo, monkeypatch):
+    """The fleet search's scorer over a 4-chip ``data`` axis: each chip
+    runs the kernel on its quarter of the population, with no gather."""
+    from repro.core.tensor_search import population_scorer
+    from repro.kernels import ops
+
+    # the described chips are not this process's backend, so "auto" would
+    # pick the CPU reference: compile the kernel branch the chip takes
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices), ("data",),
+                axis_types=(AxisType.Auto,))
+    exact = benchmark("mul_i8")
+    score = population_scorer(
+        jnp.asarray(input_truth_tables(exact.n_inputs)),
+        jnp.asarray(exact.eval_words().astype(np.int32)), mesh)
+    lits, sel = _population(4096, 16, 8, 8,
+                            NamedSharding(mesh, PartitionSpec("data")))
+    text = _compiled_text(jax.jit(score), lits, sel)
+    assert "tpu_custom_call" in text
+    assert "s32[1,1024]" in text      # one quarter of the population each
+    assert "all-gather" not in text
