@@ -431,23 +431,25 @@ def _block_decode_paged(cfg: ModelConfig, lp: Params, x, cache: Params,
                         pos, tables, active, window, lut=None):
     new_cache = dict(cache)
     h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    if cfg.mla is not None:
-        attn_out, upd = L.mla_attention_decode_paged(
-            cfg, lp["attn"], h, cache, pos, tables, active)
-    elif "kp" in cache:
-        attn_out, upd = L.attention_decode_paged(
-            cfg, lp["attn"], h, cache, pos, tables, active)
-    else:
-        attn_out, upd = L.attention_decode_ring(
-            cfg, lp["attn"], h, cache, pos, active, window)
+    with jax.named_scope("attention"):
+        if cfg.mla is not None:
+            attn_out, upd = L.mla_attention_decode_paged(
+                cfg, lp["attn"], h, cache, pos, tables, active)
+        elif "kp" in cache:
+            attn_out, upd = L.attention_decode_paged(
+                cfg, lp["attn"], h, cache, pos, tables, active)
+        else:
+            attn_out, upd = L.attention_decode_ring(
+                cfg, lp["attn"], h, cache, pos, active, window)
     new_cache.update(upd)
     x = x + attn_out
 
     h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.moe is not None:
-        mlp_out, _ = L.moe_ffn(cfg, lp["moe"], h, lut, dropless=True)
-    else:
-        mlp_out = L.ffn(cfg, lp["ffn"], h, lut)
+    with jax.named_scope("mlp"):
+        if cfg.moe is not None:
+            mlp_out, _ = L.moe_ffn(cfg, lp["moe"], h, lut, dropless=True)
+        else:
+            mlp_out = L.ffn(cfg, lp["ffn"], h, lut)
     return x + mlp_out, new_cache
 
 
@@ -474,7 +476,12 @@ def decode_step_paged(
     contract is identical — ``luts`` rides as a jitted argument (same
     TypeError guard), width maps are trace structure, and all shapes are
     fixed by ``(max_slots, pages_per_slot, page_size)``, so requests
-    joining and leaving the running batch never retrace."""
+    joining and leaving the running batch never retrace.
+
+    Named scopes label the step's operations for a profiler trace:
+    ``attention`` (projections, the paged KV write and gather, the output
+    projection), ``mlp`` (with ``quantize`` inside
+    :func:`repro.quant.approx_linear`) and ``head`` (final norm, logits)."""
     win = window_schedule(cfg)
     luts_ = luts if cfg.approx_mlp else None
     leaves = luts_.values() if isinstance(luts_, dict) else (luts_,)
@@ -513,7 +520,8 @@ def decode_step_paged(
         x, nc = _block_decode_paged(cfg, lp, x, cache, pos, tables, active,
                                     w, lut_i)
         new_caches.append(nc)
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
     return logits, new_caches

@@ -31,6 +31,23 @@ Process-global use::
 configured, so instrumented hot paths cost one attribute load when off.
 Worker processes (fork *or* spawn) auto-configure from the inherited
 ``REPRO_TRACE_DIR`` environment variable on their first span.
+
+Second sink: the JAX profiler.  When JAX is already imported and a
+profiler is collecting (``jax.profiler.start_trace`` by anyone), ``span()``
+and ``event()`` also open a ``jax.profiler.TraceAnnotation`` of the same
+name and attributes, so the program's spans sit in the profiler's trace
+beside the device operations; ``SpanHandle.set`` adds to it too.  This
+module never imports JAX itself.  With neither sink on, a span costs one
+more dictionary lookup and the profiler's own ``is_enabled`` check.
+
+Clocks: the JSONL ``t0`` is ``time.time()``; the profiler stamps host
+events with the same wall clock (``time.time_ns()``), and
+``ProfileData`` gives each event's ``start_ns`` relative to the trace's
+``profile_start_time`` (a stat of its ``Task Environment`` plane), so
+``profile_start_time + start_ns`` is a JSONL ``t0`` in nanoseconds.
+Checked on a CPU profile and on a TPU v5e host: an annotation's
+absolute start lies 10-15 microseconds after a ``time.time_ns()`` read
+just before it.
 """
 
 from __future__ import annotations
@@ -40,6 +57,7 @@ import json
 import hashlib
 import os
 import socket
+import sys
 import tempfile
 import threading
 import time
@@ -92,18 +110,22 @@ class SpanHandle:
     """What ``with span(...) as sp`` yields: lets the body attach result
     attributes (status, counts) that are only known at span end."""
 
-    __slots__ = ("name", "span_id", "parent_id", "attrs", "t0")
+    __slots__ = ("name", "span_id", "parent_id", "attrs", "t0",
+                 "annotation")
 
     def __init__(self, name: str, span_id: str, parent_id: str | None,
-                 attrs: dict, t0: float) -> None:
+                 attrs: dict, t0: float, annotation=None) -> None:
         self.name = name
         self.span_id = span_id
         self.parent_id = parent_id
         self.attrs = attrs
         self.t0 = t0
+        self.annotation = annotation   # the profiler's, while it collects
 
     def set(self, **attrs) -> "SpanHandle":
         self.attrs.update(attrs)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**attrs)
         return self
 
 
@@ -288,21 +310,42 @@ def tracing_enabled() -> bool:
     return current_tracer() is not None
 
 
+def _annotation(name: str, attrs: dict):
+    """An entered ``jax.profiler.TraceAnnotation`` when JAX is imported and
+    a profiler is collecting, else None."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    annotation = profiler.TraceAnnotation(name, **attrs)
+    annotation.__enter__()
+    return annotation
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs) -> Iterator[SpanHandle]:
-    """Module-level span against the global tracer; cheap no-op when
-    tracing is off (the yielded handle still accepts ``.set()``)."""
-    t = current_tracer()
-    if t is None:
-        yield SpanHandle(name, "", None, dict(attrs), 0.0)
-        return
-    with t.span(name, **attrs) as handle:
-        yield handle
+    """Module-level span against the global tracer and the profiler;
+    cheap no-op when both are off (the yielded handle still accepts
+    ``.set()``)."""
+    annotation = _annotation(name, attrs)
+    try:
+        t = current_tracer()
+        if t is None:
+            yield SpanHandle(name, "", None, dict(attrs), 0.0, annotation)
+            return
+        with t.span(name, **attrs) as handle:
+            handle.annotation = annotation
+            yield handle
+    finally:
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
 
 
 def event(name: str, **attrs) -> str:
     """Emit a zero-duration span; returns its id ("" when tracing is
     off) so control-plane callers can hand the id to attribution."""
+    annotation = _annotation(name, attrs)
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
     t = current_tracer()
     if t is None:
         return ""

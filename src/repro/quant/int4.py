@@ -63,19 +63,23 @@ def approx_linear(
     256x256 -> W8A8).
 
     Per-row activation scales, per-column weight scales (standard WbAb).
+    The quantization, the bias correction and the rescale sit under
+    ``jax.named_scope("quantize")``; the kernel call does not.
     """
     spec = width_from_lut(lut)
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2 = x.reshape(-1, K)
-    xq, sx = quantize_intb(x2, spec.bits, axis=-1)    # (M, K), (M, 1)
-    wq, sw = quantize_intb(w, spec.bits, axis=0)      # (K, N), (1, N)
+    with jax.named_scope("quantize"):
+        xq, sx = quantize_intb(x2, spec.bits, axis=-1)    # (M, K), (M, 1)
+        wq, sw = quantize_intb(w, spec.bits, axis=0)      # (K, N), (1, N)
 
     raw = ops.approx_matmul(xq, wq, lut, backend=backend).astype(jnp.float32)
-    # exact correction of the biased-unsigned decomposition
-    c = float(spec.bias)
-    sum_a = xq.sum(axis=1, keepdims=True).astype(jnp.float32)   # (M, 1)
-    sum_b = wq.sum(axis=0, keepdims=True).astype(jnp.float32)   # (1, N)
-    corrected = raw - c * sum_a - c * sum_b + c * c * K
-    out = corrected * sx * sw
+    with jax.named_scope("quantize"):
+        # exact correction of the biased-unsigned decomposition
+        c = float(spec.bias)
+        sum_a = xq.sum(axis=1, keepdims=True).astype(jnp.float32)  # (M, 1)
+        sum_b = wq.sum(axis=0, keepdims=True).astype(jnp.float32)  # (1, N)
+        corrected = raw - c * sum_a - c * sum_b + c * c * K
+        out = corrected * sx * sw
     return out.reshape(*lead, w.shape[1]).astype(x.dtype)

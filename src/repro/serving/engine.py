@@ -1131,69 +1131,97 @@ class ContinuousServingEngine(ServingEngine):
 
     def step_once(self, now: float | None = None) -> bool:
         """Admit what fits, then run one decode step over the pool.
-        Returns ``False`` (and runs nothing) when no slot is active."""
+        Returns ``False`` (and runs nothing) when no slot is active.
+
+        One ``serve.step`` span (attributes ``step``, ``rows`` and
+        ``prefill_rows``) holds a span per phase, in order:
+        ``serve.step.admit``, ``.inputs`` (page tables and the host
+        arrays' transfers), ``.launch`` (the jitted call), ``.wait``
+        (``block_until_ready``), ``.sample`` (drift and the argmax's copy
+        to the host) and ``.book`` (everything after)."""
         assert self._started, "call start() before step_once()"
         now = time.perf_counter() if now is None else now
-        preempts_before = self._n_preemptions
-        self._admit(now)
-        occupied = list(self._pool)
-        if not occupied:
-            return False
+        with trace_span("serve.step", step=self._step_idx) as sp:
+            preempts_before = self._n_preemptions
+            with trace_span("serve.step.admit"):
+                self._admit(now)
+            occupied = list(self._pool)
+            sp.set(rows=len(occupied))
+            if not occupied:
+                return False
 
-        toks = np.zeros((self.max_slots, 1), np.int32)
-        pos = np.zeros(self.max_slots, np.int32)
-        active = np.zeros(self.max_slots, bool)
-        tables = np.empty((self.max_slots, self.table_entries), np.int32)
-        for i in range(self.max_slots):
-            tables[i] = self._alloc.padded_table(None, self.table_entries)
-        for idx, seq in occupied:
-            toks[idx, 0] = seq.next_token()
-            pos[idx] = seq.pos
-            active[idx] = True
-            tables[idx] = self._alloc.padded_table(seq.rid,
-                                                   self.table_entries)
+            with trace_span("serve.step.inputs"):
+                toks = np.zeros((self.max_slots, 1), np.int32)
+                pos = np.zeros(self.max_slots, np.int32)
+                active = np.zeros(self.max_slots, bool)
+                tables = np.empty((self.max_slots, self.table_entries),
+                                  np.int32)
+                for i in range(self.max_slots):
+                    tables[i] = self._alloc.padded_table(None,
+                                                         self.table_entries)
+                for idx, seq in occupied:
+                    toks[idx, 0] = seq.next_token()
+                    pos[idx] = seq.pos
+                    active[idx] = True
+                    tables[idx] = self._alloc.padded_table(
+                        seq.rid, self.table_entries)
 
-        classes = sorted({seq.cls for _, seq in occupied})
-        luts, plan_b, glevel, step_level = self._resolve_stack(classes)
-        if self._adaptive and luts is None:
-            luts, plan_b = self._luts, self._plan
+                classes = sorted({seq.cls for _, seq in occupied})
+                luts, plan_b, glevel, step_level = self._resolve_stack(
+                    classes)
+                if self._adaptive and luts is None:
+                    luts, plan_b = self._luts, self._plan
 
-        jt = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(active),
-              jnp.asarray(tables))
-        want_shadow = (self._adaptive
-                       and (self._controller is not None
-                            or self._scheduler is not None)
-                       and self._step_idx % self._shadow_every == 0)
-        shadow_logits = None
-        shadow_s = 0.0
-        if want_shadow:
-            with trace_span("serve.shadow"):
-                ts = time.perf_counter()
-                shadow_caches = jax.tree.map(jnp.copy, self._caches)
-                shadow_logits, _ = self._jit_step(
-                    self.params, shadow_caches, *jt, self._exact_luts)
-                shadow_logits.block_until_ready()
-                shadow_s = time.perf_counter() - ts
-        t0 = time.perf_counter()
-        if self.inject_step_delay:
-            # chaos hook: the sleep sits inside the timed section, so an
-            # injected latency spike is indistinguishable from a real one
-            # to the telemetry, the SLO monitors and the detectors
-            time.sleep(self.inject_step_delay)
-        self._last_step_args = ((self.params, self._caches, *jt)
-                                + ((luts,) if self._adaptive else ()))
-        logits, self._caches = self._jit_step(*self._last_step_args)
-        logits.block_until_ready()
-        step_s = time.perf_counter() - t0
+                jt = (jnp.asarray(toks), jnp.asarray(pos),
+                      jnp.asarray(active), jnp.asarray(tables))
+            want_shadow = (self._adaptive
+                           and (self._controller is not None
+                                or self._scheduler is not None)
+                           and self._step_idx % self._shadow_every == 0)
+            shadow_logits = None
+            if want_shadow:
+                with trace_span("serve.shadow"):
+                    shadow_caches = jax.tree.map(jnp.copy, self._caches)
+                    shadow_logits, _ = self._jit_step(
+                        self.params, shadow_caches, *jt, self._exact_luts)
+                    shadow_logits.block_until_ready()
+            t0 = time.perf_counter()
+            with trace_span("serve.step.launch"):
+                if self.inject_step_delay:
+                    # chaos hook: the sleep sits inside the timed section,
+                    # so an injected latency spike is indistinguishable
+                    # from a real one to the telemetry, the SLO monitors
+                    # and the detectors
+                    time.sleep(self.inject_step_delay)
+                self._last_step_args = ((self.params, self._caches, *jt)
+                                        + ((luts,) if self._adaptive
+                                           else ()))
+                logits, self._caches = self._jit_step(*self._last_step_args)
+            with trace_span("serve.step.wait"):
+                logits.block_until_ready()
+            step_s = time.perf_counter() - t0
 
-        drift = None
-        if shadow_logits is not None:
-            rows = np.flatnonzero(active)
-            drift = float(jnp.abs(logits[rows]
-                                  - shadow_logits[rows]).mean())
+            with trace_span("serve.step.sample"):
+                drift = None
+                if shadow_logits is not None:
+                    rows = np.flatnonzero(active)
+                    drift = float(jnp.abs(logits[rows]
+                                          - shadow_logits[rows]).mean())
+                sampled = np.asarray(jnp.argmax(logits, axis=-1), np.int64)
+                t_done = time.perf_counter()
+            with trace_span("serve.step.book"):
+                prefill_rows = self._book(
+                    occupied, sampled, t_done, step_s, drift, plan_b, glevel,
+                    step_level, preempts_before)
+            sp.set(prefill_rows=prefill_rows)
+        return True
 
-        sampled = np.asarray(jnp.argmax(logits, axis=-1), np.int64)
-        t_done = time.perf_counter()
+    def _book(self, occupied, sampled, t_done, step_s, drift, plan_b,
+              glevel, step_level, preempts_before) -> int:
+        """Everything after a step's sample: advance each slot, evict and
+        complete finished requests, then telemetry, health, provenance,
+        costs and the control plane.  Returns how many rows fed a prompt
+        token (prefill)."""
         by_class: dict[str, dict] = {}
         for idx, seq in occupied:
             row = by_class.setdefault(
@@ -1253,12 +1281,12 @@ class ContinuousServingEngine(ServingEngine):
 
         backlog = self._queues.depth
         occ = self._pool.occupancy
+        prefill_tokens = sum(r["prefill_tokens"] for r in by_class.values())
         self.telemetry.record_step(
             step=self._step_idx, tick=self._tick, step_s=step_s,
             by_class=by_class,
             decode_tokens=sum(r["decode_tokens"] for r in by_class.values()),
-            prefill_tokens=sum(r["prefill_tokens"]
-                               for r in by_class.values()),
+            prefill_tokens=prefill_tokens,
             plan_id=plan_b.plan_id if self._adaptive else None,
             drift=drift, backlog=backlog, occupancy=occ)
         self.telemetry.record_pages(used=self._alloc.used_pages,
@@ -1279,7 +1307,7 @@ class ContinuousServingEngine(ServingEngine):
 
         self._control_plane(step_s, drift, plan_b, glevel, backlog, occ)
         self._step_idx += 1
-        return True
+        return prefill_tokens
 
     def _control_plane(self, step_s, drift, plan_b, glevel, backlog, occ):
         controller, scheduler = self._controller, self._scheduler

@@ -279,14 +279,13 @@ class Telemetry:
     def record_queue(self, qos_class: str | None, depth: int,
                      wait_s=()) -> None:
         """Queue health at admission time: current depth (gauge) plus
-        each drained request's time-in-queue — both the legacy seconds
-        histogram and a per-class queueing-delay ms histogram on SLO-
-        scale buckets (the Prometheus series request timelines read)."""
+        each drained request's time-in-queue, a per-class queueing-delay
+        ms histogram on SLO-scale buckets (the Prometheus series request
+        timelines read)."""
         cls = qos_class if qos_class is not None else ALL_CLASSES
         self.registry.gauge("serve_queue_depth",
                             **{"class": cls}).set(depth)
         for w in wait_s:
-            self._observe("serve_queue_wait_s", qos_class, float(w), None)
             self._observe("serve_queue_delay_ms", qos_class,
                           1e3 * float(w), WAIT_MS_BUCKETS)
 

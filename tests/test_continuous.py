@@ -361,6 +361,60 @@ def test_continuous_completes_all_trace_pinned(approx_setup):
         assert np.array_equal(gen, eng2.completions[rid]), rid
 
 
+STEP_PHASES = ["serve.step.admit", "serve.step.inputs", "serve.step.launch",
+               "serve.step.wait", "serve.step.sample", "serve.step.book"]
+
+
+def test_step_once_spans_each_phase_in_order(tmp_path, approx_setup):
+    """Every engine step is one ``serve.step`` span holding its six phase
+    spans, in order and nested in time; tracing serves the same tokens
+    and the step still traces once."""
+    from repro.obs import trace as obs_trace
+    from repro.obs.trace import read_trace
+
+    _, _, compiled, exact_area, cfg, params, ladder = approx_setup
+    prof = _profile()
+    plain, _ = _run_plain(cfg, params, compiled, exact_area, ladder,
+                          profile=prof)
+    obs_trace.configure(tmp_path, process_tag="serve")
+    try:
+        eng, tel = _run_plain(cfg, params, compiled, exact_area, ladder,
+                              profile=prof)
+    finally:
+        obs_trace.reset()
+    assert eng.trace_count == 1
+    assert set(eng.completions) == set(plain.completions)
+    for rid, gen in plain.completions.items():
+        assert np.array_equal(gen, eng.completions[rid]), rid
+
+    spans = read_trace(tmp_path)
+    kids: dict[str, list] = {}
+    for s in spans:
+        kids.setdefault(s["parent"], []).append(s)
+    steps = [s for s in spans if s["name"] == "serve.step"
+             and s["attrs"]["rows"] > 0]
+    assert len(steps) == tel.summary()["steps"]
+    assert [s["attrs"]["step"] for s in steps] == list(range(len(steps)))
+    for s in steps:
+        phases = sorted((k for k in kids[s["id"]]
+                         if k["name"].startswith("serve.step.")),
+                        key=lambda k: k["t0"])
+        assert [k["name"] for k in phases] == STEP_PHASES
+        assert 1 <= s["attrs"]["rows"] <= 2
+        assert 0 <= s["attrs"]["prefill_rows"] <= s["attrs"]["rows"]
+        end, ulp = s["t0"], 1e-6     # t0 + dur_s rounds in float seconds
+        for k in phases:
+            assert k["t0"] >= end - ulp
+            assert k["t0"] + k["dur_s"] <= s["t0"] + s["dur_s"] + ulp
+            end = k["t0"] + k["dur_s"]
+    # a call with nothing to run is a step span with its admission alone
+    for s in spans:
+        if s["name"] == "serve.step" and s["attrs"]["rows"] == 0:
+            assert [k["name"] for k in kids.get(s["id"], [])
+                    if k["name"].startswith("serve.step.")] == \
+                ["serve.step.admit"]
+
+
 def test_continuous_under_serving_mesh_traces_once(approx_setup):
     """The serve CLI runs the engine under its mesh: the page pools must
     start on that mesh, or the step's first outputs differ in type from

@@ -224,6 +224,83 @@ def test_global_tracer_configure_and_noop(tmp_path):
     assert os.environ.get(obs_trace.TRACE_DIR_ENV) is None
 
 
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` trace; returns the
+    ``serve.*`` host events by name and the trace's start (ns, wall)."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    env = next(p for p in data.planes if p.name == "Task Environment")
+    events = {e.name: e for p in data.planes for line in p.lines
+              for e in line.events if e.name.startswith("serve.")}
+    return events, dict(env.stats)["profile_start_time"]
+
+
+def test_spans_reach_the_profiler_on_the_jsonl_clock(tmp_path):
+    obs_trace.configure(tmp_path / "spans", process_tag="t")
+
+    def body():
+        with obs_trace.span("serve.test", step=3, who="a") as sp:
+            sp.set(rows=2)
+            with obs_trace.span("serve.test.child"):
+                pass
+        obs_trace.event("serve.mark", n=1)
+
+    events, start_ns = _profiled(tmp_path, body)
+    assert dict(events["serve.test"].stats) == {"step": 3, "who": "a",
+                                                "rows": 2}
+    assert dict(events["serve.mark"].stats) == {"n": 1}
+    parent, child = events["serve.test"], events["serve.test.child"]
+    assert parent.start_ns <= child.start_ns
+    assert child.start_ns + child.duration_ns <= \
+        parent.start_ns + parent.duration_ns
+    jsonl = {s["name"]: s for s in read_trace(tmp_path / "spans")}
+    assert jsonl["serve.test"]["attrs"] == {"step": 3, "who": "a", "rows": 2}
+    for name in ("serve.test", "serve.test.child", "serve.mark"):
+        t0_profiler = (start_ns + events[name].start_ns) * 1e-9
+        assert abs(t0_profiler - jsonl[name]["t0"]) < 1e-3
+
+
+def test_spans_reach_the_profiler_with_the_jsonl_tracer_off(tmp_path):
+    assert not obs_trace.tracing_enabled()
+
+    def body():
+        with obs_trace.span("serve.alone", k=5) as sp:
+            sp.set(rows=4)
+        assert obs_trace.event("serve.alone.mark") == ""
+
+    events, _ = _profiled(tmp_path, body)
+    assert dict(events["serve.alone"].stats) == {"k": 5, "rows": 4}
+    assert "serve.alone.mark" in events
+    assert list(tmp_path.glob("**/spans-*.jsonl")) == []
+
+
+def test_obs_trace_needs_no_jax():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(obs_trace.__file__).resolve().parents[2])
+    env = dict(os.environ, PYTHONPATH=src)
+    code = ("import sys\n"
+            "from repro.obs import trace\n"
+            "with trace.span('serve.x', a=1) as sp:\n"
+            "    sp.set(b=2)\n"
+            "trace.event('serve.y')\n"
+            "assert 'jax' not in sys.modules, 'obs.trace imported jax'\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60, env=env)
+    assert out.returncode == 0, out.stderr
+
+
 def test_metric_snapshots_roundtrip_through_trace_dir(tmp_path):
     reg = MetricRegistry()
     reg.counter("jobs", engine="anneal").inc(4)
@@ -281,7 +358,7 @@ def test_telemetry_dump_is_atomic_and_creates_parents(tmp_path):
     assert [p.name for p in out.parent.iterdir()] == ["tele.json"]
     assert tel.registry.find("serve_queue_depth",
                              **{"class": "gold"}).value == 3
-    assert tel.registry.find("serve_queue_wait_s",
+    assert tel.registry.find("serve_queue_delay_ms",
                              **{"class": "gold"}).count == 2
 
 
