@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.approx_matmul import takes_hi_pass
 from ..library.qos import (LayerPlan, plan_layer_areas, refresh_plan,
                            stack_luts, validate_lut_stack)
 from ..models import decode_fn, init_caches
@@ -88,6 +89,13 @@ class BatchStats:
     @property
     def prefill_tok_s(self) -> float:
         return self.prefill_tokens / self.prefill_s if self.prefill_s else 0.0
+
+
+def wide_lut_layers(stack) -> int:
+    """How many layers of a LUT stack (or of each stack of a mixed-width
+    dict) carry a table for which the LUT kernel runs its second pass."""
+    groups = stack.values() if isinstance(stack, dict) else (stack,)
+    return sum(takes_hi_pass(t) for g in groups for t in np.asarray(g))
 
 
 class ServingEngine:
@@ -163,16 +171,17 @@ class ServingEngine:
                 from ..precision.plans import (exact_mixed_stacks,
                                                stack_mixed_luts)
 
-                self._luts = {
-                    b: jnp.asarray(a) for b, a in stack_mixed_luts(
-                        plan, self._compiled, self._width_map).items()}
+                stack = stack_mixed_luts(plan, self._compiled,
+                                         self._width_map)
+                self._luts = {b: jnp.asarray(a) for b, a in stack.items()}
                 self._exact_luts = {
                     b: jnp.asarray(a)
                     for b, a in exact_mixed_stacks(self._width_map).items()}
                 self.width = None
                 self.widths = tuple(sorted(set(self._width_map)))
             else:
-                self._luts = jnp.asarray(stack_luts(plan, self._compiled))
+                stack = stack_luts(plan, self._compiled)
+                self._luts = jnp.asarray(stack)
                 from ..precision.widths import exact_table, width_from_stack
 
                 # the exact shadow stack shares the live stack's width — a
@@ -183,6 +192,8 @@ class ServingEngine:
                 self._exact_luts = jnp.asarray(np.broadcast_to(
                     exact_table("mul", self.width.bits).astype(np.int32),
                     (cfg.n_layers, side, side)).copy())
+            trace_event("serve.plan", plan=plan.plan_id,
+                        wide_lut_layers=wide_lut_layers(stack))
         else:
             self._luts = None
             self._exact_luts = None
@@ -251,7 +262,8 @@ class ServingEngine:
             telemetry.record_swap(batch=batch_idx, reason=reason,
                                   old=old_id, new=plan.plan_id)
         eid = trace_event("serve.swap", reason=reason, batch=batch_idx,
-                          old=old_id, new=plan.plan_id)
+                          old=old_id, new=plan.plan_id,
+                          wide_lut_layers=wide_lut_layers(stack))
         if self._health is not None:
             self._health.note_event("serve.swap", step=batch_idx,
                                     event_id=eid, reason=reason,

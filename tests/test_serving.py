@@ -52,6 +52,16 @@ def zero_mul2() -> Circuit:
     return c
 
 
+def ones_mul2() -> Circuit:
+    """Every product reads 15: a 4-bit table composed from it reaches
+    15 x 25 = 375, past what the LUT kernel's one pass takes."""
+    c = Circuit.empty(4, "ones_mul2")
+    one = c.const(True)
+    for _ in range(4):
+        c.mark_output(one)
+    return c
+
+
 def fill_library(root, circuits) -> OperatorStore:
     store = OperatorStore(root)
     exact_vals = benchmark("mul_i4").eval_words().astype(np.int64)
@@ -365,6 +375,40 @@ def test_e2e_adaptive_serve_hot_swaps_without_retrace(tmp_path):
     s = tel.summary()
     assert s["batches"] == 6 and s["requests"] == 12
     assert s["plans_used"] >= 2
+
+
+def test_plan_events_report_wide_lut_layers(tmp_path):
+    """The engine's first plan (exact tables) needs no second kernel pass;
+    a swap to a plan of the composed all-15 operator reports its layers."""
+    jax = pytest.importorskip("jax")
+    from repro.configs import get_config
+    from repro.models import init_model
+    from repro.obs import trace as obs_trace
+    from repro.serving import ServingEngine
+
+    lib = tmp_path / "lib"
+    fill_library(lib, [benchmark("mul_i4"), ones_mul2()])
+    compiled, exact_area, _ = load_mul_frontier(lib)
+    cfg = get_config("gemma3-1b", reduced=True).with_approx_mlp()
+    params = init_model(cfg, jax.random.PRNGKey(0))
+    ladder = PlanLadder.build(compiled, cfg.n_layers, exact_area=exact_area,
+                              levels=4)
+    top = len(ladder) - 1
+    ones = {r.key for r, _ in compiled if r.wce == 15}
+    routed = sum(c.key in ones for c in ladder.plan(top).choices)
+    assert routed > 0
+    obs_trace.configure(tmp_path / "trace", process_tag="plan")
+    try:
+        engine = ServingEngine(cfg, params, batch=2, prompt_len=4,
+                               gen_len=4, plan=ladder.plan(0),
+                               compiled=compiled, exact_area=exact_area)
+        assert engine.swap_plan(ladder.plan(top), ladder.luts(top))
+    finally:
+        obs_trace.reset(clear_env=True)
+    events = {s["name"]: s["attrs"]
+              for s in obs_trace.read_trace(tmp_path / "trace")}
+    assert events["serve.plan"]["wide_lut_layers"] == 0
+    assert events["serve.swap"]["wide_lut_layers"] == routed
 
 
 def test_e2e_plain_engine_single_trace(tmp_path):

@@ -3,8 +3,9 @@
 Interpret mode accepts kernels that the chip's compiler (Mosaic) refuses,
 so each kernel is compiled here for a *described* chip — one of a
 ``v5e:2x2`` topology, no device attached — at the shapes it runs at:
-the LUT matmul at the Qwen3-4B decode MLP shapes, flash attention at one
-32-head 512-token block, and ``template_eval`` at a paper-scale
+the LUT matmul at the Qwen3-4B decode MLP shapes (8, 64 and 128 rows),
+flash attention at one 32-head 512-token block, and ``template_eval`` at
+a paper-scale
 population, alone and split over the four chips the fleet search shards
 it across.  A kernel that reached the chip appears in the compiled
 program as a ``tpu_custom_call``.
@@ -26,6 +27,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.template_eval import template_eval_pallas
 
 D_MODEL, D_FF, DECODE_M = 2560, 9728, 8   # Qwen3-4B MLP, decode rows
+SERVED_M = (64, 128)      # the rows of the served chat and batch steps
 
 
 @pytest.fixture(scope="module")
@@ -59,11 +61,14 @@ def _compiled_text(fn, *args) -> str:
 
 
 @pytest.mark.parametrize("side", [16, 256], ids=["w4", "w8"])
-@pytest.mark.parametrize("K,N", [(D_MODEL, D_FF), (D_FF, D_MODEL)],
-                         ids=["up_gate", "down"])
-def test_approx_matmul_compiles_at_qwen3_mlp_shapes(one_chip, side, K, N):
+@pytest.mark.parametrize("M,K,N", [
+    pytest.param(m, k, n, id=name + ("" if m == DECODE_M else f"-m{m}"))
+    for m in (DECODE_M, *SERVED_M)
+    for name, (k, n) in (("up_gate", (D_MODEL, D_FF)),
+                         ("down", (D_FF, D_MODEL)))])
+def test_approx_matmul_compiles_at_qwen3_mlp_shapes(one_chip, side, M, K, N):
     a, b, lut = (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-                 for shape in ((DECODE_M, K), (K, N), (side, side)))
+                 for shape in ((M, K), (K, N), (side, side)))
     text = _compiled_text(approx_matmul_pallas, a, b, lut)
     assert "tpu_custom_call" in text
 
