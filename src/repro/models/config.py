@@ -84,7 +84,13 @@ class ModelConfig:
     local_global_every: int | None = None  # gemma3: every k-th layer global
     local_window: int | None = None        # window of the local layers
     rope_theta: float = 10_000.0
+    rotary_fraction: float = 1.0           # share of each head's dims rotary
+    #                                        turns (StableLM-2: 0.25); the
+    #                                        rest pass through unrotated
+    qkv_bias: bool = False                 # q, k, v projections add a bias
     tie_embeddings: bool = False
+    norm: Literal["rms", "layer"] = "rms"  # block and final norms: RMSNorm,
+    #                                        or LayerNorm with a bias
     norm_eps: float = 1e-6
     # --- family sub-configs ---
     moe: MoEConfig | None = None
@@ -108,6 +114,11 @@ class ModelConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def rotary_dims(self) -> int:
+        """Leading dims of each head that rotary turns."""
+        return int(self.hd * self.rotary_fraction)
 
     @property
     def attn_free(self) -> bool:
@@ -149,6 +160,8 @@ class ModelConfig:
             per_layer += H * mla.v_head_dim * D
         else:
             per_layer += D * H * hd + 2 * D * Hkv * hd + H * hd * D
+            if self.qkv_bias:
+                per_layer += (H + 2 * Hkv) * hd
         if self.ssm is not None:  # hybrid adds the SSM path on top of attn
             di = self.ssm.d_inner or D
             per_layer += D * di + di * (2 * self.ssm.state_dim) + di * D

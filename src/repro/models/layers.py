@@ -52,6 +52,26 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * (1.0 + w)
 
 
+def layernorm(x: jax.Array, w: jax.Array, b: jax.Array,
+              eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with gain ``1 + w`` (the repo's gain convention) and a
+    bias ``b``."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps)).astype(x.dtype) * (1.0 + w) + b
+
+
+def block_norm(cfg: ModelConfig, p: Params, name: str,
+               x: jax.Array) -> jax.Array:
+    """The model's norm ``p[name]`` (``ln1``, ``ln2``, ``ln_f``): RMSNorm,
+    or with ``cfg.norm == "layer"`` LayerNorm with the bias
+    ``p[name + "_b"]``."""
+    if cfg.norm == "layer":
+        return layernorm(x, p[name], p[name + "_b"], cfg.norm_eps)
+    return rmsnorm(x, p[name], cfg.norm_eps)
+
+
 def linear(x: jax.Array, w: jax.Array, lut: jax.Array | None = None) -> jax.Array:
     """Matmul, optionally routed through the approximate-multiplier LUT."""
     if lut is not None:
@@ -59,21 +79,28 @@ def linear(x: jax.Array, w: jax.Array, lut: jax.Array | None = None) -> jax.Arra
     return jnp.einsum("...d,df->...f", x, w)
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """positions (...,) -> cos/sin tables (..., head_dim//2)."""
-    half = head_dim // 2
+def rope_tables(positions: jax.Array, rot_dims: int, theta: float):
+    """positions (...,) -> cos/sin tables (..., rot_dims//2) for rotary
+    over ``rot_dims`` dims (the head, or its leading share)."""
+    half = rot_dims // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(angles), jnp.sin(angles)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x (..., S, H, hd); cos/sin (..., S, hd//2) — half-split rotation."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    """x (..., S, H, hd); cos/sin (..., S, rot//2) — half-split rotation
+    of the leading ``rot`` dims; dims ``rot:`` pass through (partial
+    rotary, where the tables are narrower than the head)."""
+    rot = 2 * cos.shape[-1]
+    half = rot // 2
+    x1, x2 = x[..., :half], x[..., half:rot]
     c = cos[..., None, :]  # broadcast over heads
     s = sin[..., None, :]
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    parts = [x1 * c - x2 * s, x2 * c + x1 * s]
+    if rot < x.shape[-1]:
+        parts.append(x[..., rot:])
+    out = jnp.concatenate(parts, axis=-1)
     return out.astype(x.dtype)
 
 
@@ -93,15 +120,24 @@ def init_attention(cfg: ModelConfig, key) -> Params:
     if cfg.qk_norm:
         p["q_norm"] = jnp.zeros((hd,), dt)
         p["k_norm"] = jnp.zeros((hd,), dt)
+    if cfg.qkv_bias:
+        p["bq"] = jnp.zeros((H * hd,), dt)
+        p["bk"] = jnp.zeros((Hkv * hd,), dt)
+        p["bv"] = jnp.zeros((Hkv * hd,), dt)
     return p
 
 
 def _qkv(cfg: ModelConfig, p: Params, x: jax.Array):
     B, S, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = linear(x, p["wq"]).reshape(B, S, H, hd)
-    k = linear(x, p["wk"]).reshape(B, S, Hkv, hd)
-    v = linear(x, p["wv"]).reshape(B, S, Hkv, hd)
+
+    def proj(name, heads):
+        y = linear(x, p["w" + name])
+        if cfg.qkv_bias:
+            y = y + p["b" + name]
+        return y.reshape(B, S, heads, hd)
+
+    q, k, v = proj("q", H), proj("k", Hkv), proj("v", Hkv)
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
@@ -169,7 +205,7 @@ def attention_full(
     B, S, _ = x.shape
     q, k, v = _qkv(cfg, p, x)
     pos = jnp.arange(S)
-    cos, sin = rope_tables(pos, cfg.hd, cfg.rope_theta)
+    cos, sin = rope_tables(pos, cfg.rotary_dims, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     q = shard(q, "batch", None, "model", None)
@@ -200,7 +236,7 @@ def attention_decode(
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x)
-    cos, sin = rope_tables(pos[None], cfg.hd, cfg.rope_theta)
+    cos, sin = rope_tables(pos[None], cfg.rotary_dims, cfg.rope_theta)
     q = apply_rope(q, cos[None], sin[None])
     k_new = apply_rope(k_new, cos[None], sin[None])
 
@@ -365,9 +401,10 @@ def _decode_attn_rows(q, k, v, mask, f32_math: bool = True):
     return out.astype(out_dtype)
 
 
-def _rows_rope(x, pos, head_dim, theta):
-    """Per-row rope for single-token decode: x (B, 1, H, hd), pos (B,)."""
-    cos, sin = rope_tables(pos, head_dim, theta)      # (B, hd//2)
+def _rows_rope(x, pos, rot_dims, theta):
+    """Per-row rope for single-token decode: x (B, 1, H, hd), pos (B,);
+    rotary over the leading ``rot_dims`` dims of each head."""
+    cos, sin = rope_tables(pos, rot_dims, theta)      # (B, rot_dims//2)
     return apply_rope(x, cos[:, None], sin[:, None])
 
 
@@ -388,8 +425,8 @@ def attention_decode_ring(
     ``pos``, never from what the buffer happens to contain."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x)
-    q = _rows_rope(q, pos, cfg.hd, cfg.rope_theta)
-    k_new = _rows_rope(k_new, pos, cfg.hd, cfg.rope_theta)
+    q = _rows_rope(q, pos, cfg.rotary_dims, cfg.rope_theta)
+    k_new = _rows_rope(k_new, pos, cfg.rotary_dims, cfg.rope_theta)
 
     C = cache["k"].shape[1]
     slot = pos % C
@@ -451,8 +488,8 @@ def attention_decode_paged(
     masked by ``j <= pos``, so page *reuse* needs no zeroing."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x)
-    q = _rows_rope(q, pos, cfg.hd, cfg.rope_theta)
-    k_new = _rows_rope(k_new, pos, cfg.hd, cfg.rope_theta)
+    q = _rows_rope(q, pos, cfg.rotary_dims, cfg.rope_theta)
+    k_new = _rows_rope(k_new, pos, cfg.rotary_dims, cfg.rope_theta)
 
     kp = _paged_write(cache["kp"], k_new[:, 0], pos, tables)
     vp = _paged_write(cache["vp"], v_new[:, 0], pos, tables)

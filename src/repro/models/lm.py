@@ -52,6 +52,9 @@ def _init_layer(cfg: ModelConfig, key) -> Params:
     ks = list(jax.random.split(key, 4))
     dt = cfg.jnp_dtype
     p: Params = {"ln1": jnp.zeros((cfg.d_model,), dt), "ln2": jnp.zeros((cfg.d_model,), dt)}
+    if cfg.norm == "layer":
+        p["ln1_b"] = jnp.zeros((cfg.d_model,), dt)
+        p["ln2_b"] = jnp.zeros((cfg.d_model,), dt)
     if cfg.rwkv is not None:
         p["rwkv"] = L.init_rwkv(cfg, ks[0])
         return p
@@ -80,6 +83,8 @@ def init_lm(cfg: ModelConfig, key) -> Params:
         "layers": stacked,
         "ln_f": jnp.zeros((cfg.d_model,), dt),
     }
+    if cfg.norm == "layer":
+        params["ln_f_b"] = jnp.zeros((cfg.d_model,), dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = L._dense_init(ks[-2], (cfg.d_model, cfg.vocab_size), dt)
     if cfg.vision is not None:
@@ -99,7 +104,7 @@ def _block_full(cfg: ModelConfig, lp: Params, x, window, lut, backend):
         h, _ = L.rwkv_channel_mix(cfg, lp["rwkv"], L.rmsnorm(x, lp["ln2"], cfg.norm_eps))
         return x + h, jnp.float32(0.0)
 
-    h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln1", x)
     if cfg.mla is not None:
         attn_out = L.mla_attention_full(cfg, lp["attn"], h)
     else:
@@ -109,7 +114,7 @@ def _block_full(cfg: ModelConfig, lp: Params, x, window, lut, backend):
         attn_out = 0.5 * (attn_out + ssm_out)
     x = x + attn_out
 
-    h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln2", x)
     if cfg.moe is not None:
         mlp_out, aux = L.moe_ffn(cfg, lp["moe"], h, lut)
     else:
@@ -177,7 +182,7 @@ def forward_lm(
         body, (x, jnp.float32(0.0)), xs, unroll=True if scan_unroll else 1
     )
 
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    x = L.block_norm(cfg, params, "ln_f", x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
     logits = shard(logits, "batch", None, "model")
@@ -319,7 +324,7 @@ def _block_decode(cfg: ModelConfig, lp: Params, x, cache: Params, pos, window,
         new_cache.update(x_tm=x_tm, wkv=wkv, x_cm=x_cm)
         return x + out, new_cache
 
-    h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln1", x)
     if cfg.mla is not None:
         attn_out, upd = L.mla_attention_decode(cfg, lp["attn"], h, cache, pos)
     else:
@@ -331,7 +336,7 @@ def _block_decode(cfg: ModelConfig, lp: Params, x, cache: Params, pos, window,
         attn_out = 0.5 * (attn_out + ssm_out)
     x = x + attn_out
 
-    h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln2", x)
     if cfg.moe is not None:
         mlp_out, _ = L.moe_ffn(cfg, lp["moe"], h, lut, dropless=True)
     else:
@@ -418,7 +423,7 @@ def decode_step(
             lut_i = luts_[i] if jnp.ndim(luts_) == 3 else luts_
         x, nc = _block_decode(cfg, lp, x, cache, pos, w, lut_i)
         new_caches.append(nc)
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    x = L.block_norm(cfg, params, "ln_f", x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
     return logits, new_caches
@@ -430,7 +435,7 @@ def decode_step(
 def _block_decode_paged(cfg: ModelConfig, lp: Params, x, cache: Params,
                         pos, tables, active, window, lut=None):
     new_cache = dict(cache)
-    h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln1", x)
     with jax.named_scope("attention"):
         if cfg.mla is not None:
             attn_out, upd = L.mla_attention_decode_paged(
@@ -444,7 +449,7 @@ def _block_decode_paged(cfg: ModelConfig, lp: Params, x, cache: Params,
     new_cache.update(upd)
     x = x + attn_out
 
-    h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    h = L.block_norm(cfg, lp, "ln2", x)
     with jax.named_scope("mlp"):
         if cfg.moe is not None:
             mlp_out, _ = L.moe_ffn(cfg, lp["moe"], h, lut, dropless=True)
@@ -521,7 +526,7 @@ def decode_step_paged(
                                     w, lut_i)
         new_caches.append(nc)
     with jax.named_scope("head"):
-        x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        x = L.block_norm(cfg, params, "ln_f", x)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
     return logits, new_caches

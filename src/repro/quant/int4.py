@@ -64,7 +64,9 @@ def approx_linear(
 
     Per-row activation scales, per-column weight scales (standard WbAb).
     The quantization, the bias correction and the rescale sit under
-    ``jax.named_scope("quantize")``; the kernel call does not.
+    ``jax.named_scope("quantize")``; the kernel call with its wrapper
+    work (padding, tile extraction, output conversion) under
+    ``lut.w<bits>`` (``lut.w4``, ``lut.w8``).
     """
     spec = width_from_lut(lut)
     lead = x.shape[:-1]
@@ -74,7 +76,9 @@ def approx_linear(
         xq, sx = quantize_intb(x2, spec.bits, axis=-1)    # (M, K), (M, 1)
         wq, sw = quantize_intb(w, spec.bits, axis=0)      # (K, N), (1, N)
 
-    raw = ops.approx_matmul(xq, wq, lut, backend=backend).astype(jnp.float32)
+    with jax.named_scope(f"lut.w{spec.bits}"):
+        raw = ops.approx_matmul(xq, wq, lut,
+                                backend=backend).astype(jnp.float32)
     with jax.named_scope("quantize"):
         # exact correction of the biased-unsigned decomposition
         c = float(spec.bias)

@@ -193,6 +193,7 @@ class ServingEngine:
                     exact_table("mul", self.width.bits).astype(np.int32),
                     (cfg.n_layers, side, side)).copy())
             trace_event("serve.plan", plan=plan.plan_id,
+                        lut_bits=self.lut_bits,
                         wide_lut_layers=wide_lut_layers(stack))
         else:
             self._luts = None
@@ -232,6 +233,13 @@ class ServingEngine:
         return self._trace_count
 
     @property
+    def lut_bits(self) -> int | str:
+        """The LUT operand width the plan serves (4 or 8); a mixed-width
+        plan gives its widths joined, as ``"4+8"``."""
+        return (self.widths[0] if len(self.widths) == 1
+                else "+".join(map(str, self.widths)))
+
+    @property
     def plan(self) -> LayerPlan | None:
         return self._plan
 
@@ -263,6 +271,7 @@ class ServingEngine:
                                   old=old_id, new=plan.plan_id)
         eid = trace_event("serve.swap", reason=reason, batch=batch_idx,
                           old=old_id, new=plan.plan_id,
+                          lut_bits=self.lut_bits,
                           wide_lut_layers=wide_lut_layers(stack))
         if self._health is not None:
             self._health.note_event("serve.swap", step=batch_idx,

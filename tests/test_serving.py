@@ -409,6 +409,8 @@ def test_plan_events_report_wide_lut_layers(tmp_path):
               for s in obs_trace.read_trace(tmp_path / "trace")}
     assert events["serve.plan"]["wide_lut_layers"] == 0
     assert events["serve.swap"]["wide_lut_layers"] == routed
+    assert events["serve.plan"]["lut_bits"] == 4
+    assert events["serve.swap"]["lut_bits"] == 4
 
 
 def test_e2e_plain_engine_single_trace(tmp_path):

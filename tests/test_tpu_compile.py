@@ -3,9 +3,9 @@
 Interpret mode accepts kernels that the chip's compiler (Mosaic) refuses,
 so each kernel is compiled here for a *described* chip — one of a
 ``v5e:2x2`` topology, no device attached — at the shapes it runs at:
-the LUT matmul at the Qwen3-4B decode MLP shapes (8, 64 and 128 rows),
-flash attention at one 32-head 512-token block, and ``template_eval`` at
-a paper-scale
+the LUT matmul at the Qwen3-4B decode MLP shapes (8, 64 and 128 rows)
+and at StableLM-2-1.6B's on its 8-bit path, flash attention at one
+32-head 512-token block, and ``template_eval`` at a paper-scale
 population, alone and split over the four chips the fleet search shards
 it across.  A kernel that reached the chip appears in the compiled
 program as a ``tpu_custom_call``.
@@ -69,6 +69,18 @@ def _compiled_text(fn, *args) -> str:
 def test_approx_matmul_compiles_at_qwen3_mlp_shapes(one_chip, side, M, K, N):
     a, b, lut = (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
                  for shape in ((M, K), (K, N), (side, side)))
+    text = _compiled_text(approx_matmul_pallas, a, b, lut)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("K,N", [(2048, 5632), (5632, 2048)],
+                         ids=["up_gate", "down"])
+def test_w8_approx_matmul_compiles_at_stablelm2_mlp_shapes(one_chip, K, N):
+    """The 8-bit path at the shapes the StableLM-2-1.6B cell serves, its
+    whole 128-row block: K = 5632 is no multiple of the path's 227-wide
+    K blocks."""
+    a, b, lut = (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+                 for shape in ((128, K), (K, N), (256, 256)))
     text = _compiled_text(approx_matmul_pallas, a, b, lut)
     assert "tpu_custom_call" in text
 
