@@ -101,8 +101,11 @@ class ModelConfig:
     vision: VisionConfig | None = None
     # --- numerics ---
     dtype: str = "bfloat16"
-    attn_f32: bool = True   # f32 QK^T/PV einsums (baseline); False = bf16
-    #                         inputs with f32 accumulation (§Perf iteration)
+    attn_f32: bool = True   # full-sequence and dense-decode attention
+    #                         (_masked_softmax_attn): f32 QK^T/PV einsums;
+    #                         False = bf16 inputs with f32 accumulation.  The
+    #                         paged and ring decode paths ignore it: they
+    #                         contract bf16 K/V into f32 (_decode_attn_rows)
     # --- approximate-arithmetic emulation (the paper's Layer B hook) ---
     approx_mlp: bool = False               # route MLP matmuls through the LUT
     approx_bits: int = 4                   # LUT operand width: 4 (W4A4 native)

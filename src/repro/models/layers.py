@@ -367,38 +367,47 @@ def mla_attention_decode(
 # shapes are fixed by (max_slots, pages_per_slot, page_size), so the
 # serving engine's single-trace contract survives joins and leaves.
 # ---------------------------------------------------------------------------
-def _decode_attn_rows(q, k, v, mask, f32_math: bool = True):
+def _decode_attn_rows(q, k, v, mask):
     """Single-token attention with a per-row key mask.
 
     q (B, 1, H, hd); k/v (B, K, Hkv, hd); mask (B, K) bool — True where
     row b may attend to key slot j.  The caller guarantees every row has
     at least one True (inactive rows point at one masked-garbage slot so
     the softmax never sees an all ``-inf`` row).
+
+    Query head ``h·G + g`` (``G = H // Hkv``; 1 for MHA) reads KV head
+    ``h``, so the G query heads of a group are contracted together against
+    K and V as gathered: nothing repeats them to H heads or widens them.
+    QKᵀ multiplies in the operands' dtype into f32, exact for bf16; mask
+    and softmax are f32.  The probabilities enter PV split into three bf16
+    terms stacked in one contraction: together they hold all 24 bits of
+    each f32 probability, and each product with V's bf16 values is exact in
+    f32, so the result is the f32 PV's up to accumulation order.
     """
     B, _, H, hd = q.shape
-    out_dtype = q.dtype
     Hkv = k.shape[2]
-    if Hkv != H:
-        rep = H // Hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    G = H // Hkv
     k = shard(k, "batch", None, "model", None)
     v = shard(v, "batch", None, "model", None)
-    scale = 1.0 / np.sqrt(hd)
-    if f32_math:
-        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
+    qg = q.reshape(B, Hkv, G, hd)
+    # a zero row keeps QKᵀ a matrix product at G = 1: XLA rewrites a
+    # one-row contraction as an f32 multiply-reduce over a widened copy of K
+    qg = jnp.concatenate([qg, jnp.zeros_like(qg[:, :, :1])], axis=2)
+    logits = jnp.einsum("bhgd,bkhd->bhgk", qg, k,
+                        preferred_element_type=jnp.float32)[:, :, :G]
+    logits = logits / np.sqrt(hd)
     logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    if f32_math:
-        v = v.astype(jnp.float32)
-    else:
-        probs = probs.astype(v.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                     preferred_element_type=jnp.float32)
+    terms = []
+    for _ in range(3):
+        terms.append(probs.astype(v.dtype))
+        probs = probs - terms[-1].astype(jnp.float32)
+    pv = jnp.einsum("bhgk,bkhd->bhgd", jnp.concatenate(terms, axis=2), v,
+                    preferred_element_type=jnp.float32)
+    out = (pv[:, :, :G] + pv[:, :, G:2 * G] + pv[:, :, 2 * G:])
+    out = out.reshape(B, 1, H, hd)
     out = shard(out, "batch", None, "model", None)
-    return out.astype(out_dtype)
+    return out.astype(q.dtype)
 
 
 def _rows_rope(x, pos, rot_dims, theta):
@@ -444,7 +453,7 @@ def attention_decode_ring(
     # inactive rows attend to exactly slot 0 (output discarded, but the
     # softmax must not see an empty row)
     mask = jnp.where(active[:, None], mask, idx == 0)
-    out = _decode_attn_rows(q, k, v, mask, f32_math=cfg.attn_f32)
+    out = _decode_attn_rows(q, k, v, mask)
     out = linear(out.reshape(B, 1, -1), p["wo"])
     return out, new_cache
 
@@ -500,7 +509,7 @@ def attention_decode_paged(
     idx = jnp.arange(k.shape[1])[None, :]             # logical positions
     mask = idx <= pos[:, None]
     mask = jnp.where(active[:, None], mask, idx == 0)
-    out = _decode_attn_rows(q, k, v, mask, f32_math=cfg.attn_f32)
+    out = _decode_attn_rows(q, k, v, mask)
     out = linear(out.reshape(B, 1, -1), p["wo"])
     return out, new_cache
 

@@ -8,10 +8,14 @@ and at StableLM-2-1.6B's on its 8-bit path, flash attention at one
 32-head 512-token block, and ``template_eval`` at a paper-scale
 population, alone and split over the four chips the fleet search shards
 it across.  A kernel that reached the chip appears in the compiled
-program as a ``tpu_custom_call``.
+program as a ``tpu_custom_call``.  One paged decode attention layer is
+compiled at each batch cell's shapes, to hold the contraction to the bf16
+gather of K and V.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +29,8 @@ from repro.core.circuits import input_truth_tables
 from repro.kernels.approx_matmul import approx_matmul_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.template_eval import template_eval_pallas
+from repro.models.config import ModelConfig
+from repro.models.layers import attention_decode_paged
 
 D_MODEL, D_FF, DECODE_M = 2560, 9728, 8   # Qwen3-4B MLP, decode rows
 SERVED_M = (64, 128)      # the rows of the served chat and batch steps
@@ -128,3 +134,54 @@ def test_sharded_template_eval_compiles_on_four_chips(topo, monkeypatch):
     assert "tpu_custom_call" in text
     assert "s32[1,1024]" in text      # one quarter of the population each
     assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("D,Hkv,hd,extra", [
+    (2048, 32, 64, dict(qkv_bias=True, rotary_fraction=0.25)),
+    (2560, 8, 128, dict(qk_norm=True))], ids=["stablelm2-mha", "qwen3-gqa"])
+def test_paged_decode_attention_reads_the_bf16_gather(one_chip, D, Hkv, hd,
+                                                      extra):
+    """One ``attention_decode_paged`` layer at a batch cell's shapes: 128
+    slots of 20 pages of 8 positions, the pool as the engine sizes it.  No
+    f32 array as large as the gathered K may appear anywhere in the
+    program, nor (GQA) K or V repeated to the 32 query heads; the temp
+    holds at most both pools in the page-major layout the scatter and
+    gather use and both bf16 gathers, each padded to 128 lanes (an f32
+    copy of one gather alone is twice a gather)."""
+    H, slots, pages, page = 32, 128, 20, 8
+    n_pool = (slots + 1) * pages + 1
+    cfg = ModelConfig(name="attn", family="dense", n_layers=1, d_model=D,
+                      n_heads=H, n_kv_heads=Hkv, d_ff=4 * D, vocab_size=16,
+                      head_dim=hd, **extra)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"wq": spec((D, H * hd)), "wk": spec((D, Hkv * hd)),
+         "wv": spec((D, Hkv * hd)), "wo": spec((H * hd, D))}
+    if cfg.qk_norm:
+        p.update(q_norm=spec((hd,)), k_norm=spec((hd,)))
+    if cfg.qkv_bias:
+        p.update(bq=spec((H * hd,)), bk=spec((Hkv * hd,)),
+                 bv=spec((Hkv * hd,)))
+    pool = spec((n_pool, page, Hkv, hd))
+    step = jax.jit(functools.partial(attention_decode_paged, cfg),
+                   donate_argnums=(2,))
+    compiled = step.lower(p, spec((slots, 1, D)), {"kp": pool, "vp": pool},
+                          spec((slots,), jnp.int32),
+                          spec((slots, pages), jnp.int32),
+                          spec((slots,), jnp.bool_)).compile()
+
+    gathered = slots * pages * page * Hkv * hd
+    arrays = [(dt, int(np.prod([int(n) for n in dims.split(",")])), dims)
+              for dt, dims in re.findall(r"\b(\w+)\[([\d,]+)\]",
+                                         compiled.as_text())]
+    widened = {f"{dt}[{dims}]" for dt, n, dims in arrays
+               if dt == "f32" and n >= gathered}
+    assert not widened, f"f32 arrays as large as the gathered K: {widened}"
+    repeated = {f"{dt}[{dims}]" for dt, n, dims in arrays
+                if H > Hkv and n >= gathered * H // Hkv}
+    assert not repeated, f"K/V repeated to the query heads: {repeated}"
+    lanes = max(hd, 128) / hd
+    bound = 2 * 2 * lanes * (n_pool + slots * pages) * page * Hkv * hd
+    assert compiled.memory_analysis().temp_size_in_bytes <= bound
