@@ -25,9 +25,7 @@ class MoEConfig:
     n_shared: int = 0              # always-on shared experts (DeepSeek)
     router_noise: float = 0.0
     aux_loss_weight: float = 0.01  # load-balancing loss
-    impl: str = "blocked"          # 'blocked' (capacity batched-matmul) |
-    #                                'ragged' (lax.ragged_dot) — see §Perf
-    capacity_factor: float = 1.25  # blocked impl: slots = T*K/E * cf
+    capacity_factor: float = 1.25  # expert block slots = T*K/E * cf
 
 
 @dataclass(frozen=True)
@@ -101,11 +99,6 @@ class ModelConfig:
     vision: VisionConfig | None = None
     # --- numerics ---
     dtype: str = "bfloat16"
-    attn_f32: bool = True   # full-sequence and dense-decode attention
-    #                         (_masked_softmax_attn): f32 QK^T/PV einsums;
-    #                         False = bf16 inputs with f32 accumulation.  The
-    #                         paged and ring decode paths ignore it: they
-    #                         contract bf16 K/V into f32 (_decode_attn_rows)
     # --- approximate-arithmetic emulation (the paper's Layer B hook) ---
     approx_mlp: bool = False               # route MLP matmuls through the LUT
     approx_bits: int = 4                   # LUT operand width: 4 (W4A4 native)

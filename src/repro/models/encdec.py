@@ -82,7 +82,7 @@ def _bidir_attention(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     pos = jnp.arange(S)
     big = jnp.full((S,), -1, jnp.int32)  # everything visible: use k_pos <= +inf
     out = L._masked_softmax_attn(q, k, v, jnp.full((S,), S, jnp.int32), pos,
-                                 None, f32_math=cfg.attn_f32)
+                                 None)
     return L.linear(out.reshape(B, S, -1), p["wo"])
 
 
@@ -93,8 +93,7 @@ def _cross_attention(cfg: ModelConfig, p: Params, x, enc_k, enc_v) -> jax.Array:
     q = L.linear(x, p["wq"]).reshape(B, Sq, H, hd)
     Sk = enc_k.shape[1]
     out = L._masked_softmax_attn(
-        q, enc_k, enc_v, jnp.full((Sq,), Sk, jnp.int32), jnp.arange(Sk), None,
-        f32_math=cfg.attn_f32,
+        q, enc_k, enc_v, jnp.full((Sq,), Sk, jnp.int32), jnp.arange(Sk), None
     )
     return L.linear(out.reshape(B, Sq, -1), p["wo"])
 
@@ -141,8 +140,7 @@ def forward_encdec(
         q, k, v = L._qkv(cfg, lp["attn"], h)
         pos = jnp.arange(S)
         x = x + L.linear(
-            L._masked_softmax_attn(q, k, v, pos, pos, None,
-                                   f32_math=cfg.attn_f32).reshape(B, S, -1),
+            L._masked_softmax_attn(q, k, v, pos, pos, None).reshape(B, S, -1),
             lp["attn"]["wo"],
         )
         h = L.rmsnorm(x, lp["ln_x"], cfg.norm_eps)

@@ -144,8 +144,7 @@ def _qkv(cfg: ModelConfig, p: Params, x: jax.Array):
     return q, k, v
 
 
-def _masked_softmax_attn(q, k, v, q_pos, k_pos, window, k_valid=None,
-                         f32_math: bool = True):
+def _masked_softmax_attn(q, k, v, q_pos, k_pos, window, k_valid=None):
     """Flat-head einsum attention with causal + (traced) window masking.
 
     q (B, Sq, H, hd); k/v (B, Sk, Hkv, hd); q_pos (Sq,), k_pos (Sk,).
@@ -168,9 +167,7 @@ def _masked_softmax_attn(q, k, v, q_pos, k_pos, window, k_valid=None,
     k = shard(k, "batch", None, "model", None)
     v = shard(v, "batch", None, "model", None)
     scale = 1.0 / np.sqrt(hd)
-    if f32_math:
-        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-    # bf16 inputs + f32 accumulation (MXU-native) when f32_math is off
+    q, k = q.astype(jnp.float32), k.astype(jnp.float32)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     logits = shard(logits, "batch", "model", None, None)
@@ -183,10 +180,7 @@ def _masked_softmax_attn(q, k, v, q_pos, k_pos, window, k_valid=None,
         mask = mask & k_valid[None, :]
     logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    if f32_math:
-        v = v.astype(jnp.float32)
-    else:
-        probs = probs.astype(v.dtype)
+    v = v.astype(jnp.float32)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
                      preferred_element_type=jnp.float32)
     out = shard(out, "batch", None, "model", None)
@@ -220,8 +214,7 @@ def attention_full(
             causal=True, window=window, backend=backend,
         ).transpose(0, 2, 1, 3)
     else:
-        out = _masked_softmax_attn(q, k, v, pos, pos, window,
-                                   f32_math=cfg.attn_f32)
+        out = _masked_softmax_attn(q, k, v, pos, pos, window)
     out = shard(out, "batch", None, "model", None)
     return linear(out.reshape(B, S, -1), p["wo"])
 
@@ -255,8 +248,7 @@ def attention_decode(
         idx = jnp.arange(C)
         k_pos = idx
         k_valid = idx <= pos
-    out = _masked_softmax_attn(q, k, v, pos[None], k_pos, None, k_valid,
-                               f32_math=cfg.attn_f32)
+    out = _masked_softmax_attn(q, k, v, pos[None], k_pos, None, k_valid)
     out = linear(out.reshape(B, 1, -1), p["wo"])
     return out, new_cache
 
@@ -301,8 +293,7 @@ def mla_attention_full(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
 
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
     kfull = jnp.concatenate([k_nope, k_rope], axis=-1)
-    out = _masked_softmax_attn(qfull, kfull, v, pos, pos, None,
-                               f32_math=cfg.attn_f32)
+    out = _masked_softmax_attn(qfull, kfull, v, pos, pos, None)
     return linear(out.reshape(B, S, -1), p["wo"])
 
 
@@ -595,7 +586,7 @@ def ffn(cfg: ModelConfig, p: Params, x: jax.Array, lut=None) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# MoE FFN: sort-based dispatch + ragged_dot (exact active FLOPs)
+# MoE FFN: sort-based dispatch into fixed-capacity expert blocks
 # ---------------------------------------------------------------------------
 def init_moe(cfg: ModelConfig, key) -> Params:
     mo = cfg.moe
@@ -618,18 +609,12 @@ def init_moe(cfg: ModelConfig, key) -> Params:
 
 def moe_ffn(cfg: ModelConfig, p: Params, x: jax.Array, lut=None,
             dropless: bool = False):
-    """Returns (out, aux_loss).  Two dispatch implementations:
-
-    * ``blocked`` (default): sort tokens by expert, pack each expert's
-      tokens into a fixed-capacity block (megablocks-lite), run the expert
-      stack as *batched matmuls* ``(E, C, D) x (E, D, F)``.  FLOPs =
-      capacity_factor x active FLOPs in BOTH forward and backward, and the
-      batched-matmul VJP partitions cleanly under GSPMD.  Overflow tokens
-      beyond capacity are dropped (standard GShard/Switch semantics).
-    * ``ragged``: dropless ``lax.ragged_dot``.  Exact, but its XLA
-      lowering (and its VJP in particular) densifies to all-experts
-      compute on non-Mosaic backends — E x overcompute (measured 8x fwd /
-      8x bwd for E=8; see EXPERIMENTS.md §Perf iteration 1).
+    """Returns (out, aux_loss).  Sort tokens by expert, pack each expert's
+    tokens into a fixed-capacity block (megablocks-lite), run the expert
+    stack as *batched matmuls* ``(E, C, D) x (E, D, F)``.  FLOPs =
+    capacity_factor x active FLOPs in BOTH forward and backward, and the
+    batched-matmul VJP partitions cleanly under GSPMD.  Overflow tokens
+    beyond capacity are dropped (standard GShard/Switch semantics).
     """
     mo = cfg.moe
     B, S, D = x.shape
@@ -652,48 +637,37 @@ def moe_ffn(cfg: ModelConfig, p: Params, x: jax.Array, lut=None,
     order = jnp.argsort(flat_e)
     group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
 
-    if mo.impl == "ragged":
-        xs = flat[flat_t[order]]                             # (T*K, D)
-        h1 = jax.lax.ragged_dot(xs, p["w1"], group_sizes)
-        h3 = jax.lax.ragged_dot(xs, p["w3"], group_sizes)
-        hs = jax.nn.silu(h1) * h3
-        hs = shard(hs, "batch", "model")
-        ys = jax.lax.ragged_dot(hs, p["w2"], group_sizes)    # (T*K, D)
-        out = jnp.zeros((T, D), jnp.float32)
-        out = out.at[flat_t[order]].add(
-            ys.astype(jnp.float32) * flat_g[order][:, None])
+    if dropless:
+        # decode: per-step token counts are tiny and token dropping
+        # would break decode == teacher-forced-forward; worst case all
+        # tokens route to one expert -> capacity T*K is exact
+        C = T * K
     else:
-        if dropless:
-            # decode: per-step token counts are tiny and token dropping
-            # would break decode == teacher-forced-forward; worst case all
-            # tokens route to one expert -> capacity T*K is exact
-            C = T * K
-        else:
-            C = max(1, int(np.ceil(T * K / E * mo.capacity_factor)))
-        starts = jnp.cumsum(group_sizes) - group_sizes       # (E,)
-        slot_c = jax.lax.broadcasted_iota(jnp.int32, (E, C), 1)
-        src = starts[:, None] + slot_c                       # (E, C) into order
-        valid = slot_c < group_sizes[:, None]
-        src = jnp.minimum(src, T * K - 1)
-        rows = flat_t[order][src]                            # (E, C) token ids
-        g_blk = jnp.where(valid, flat_g[order][src], 0.0)    # (E, C)
-        xs = flat[rows] * valid[..., None].astype(flat.dtype)  # (E, C, D)
-        # expert parallelism when E divides the data axis (the classic MoE
-        # all-to-all appears at the gather/scatter boundary); otherwise the
-        # capacity axis stays data-parallel and expert weights stay FSDP
-        ep = E % max(1, axis_extent("expert")) == 0 and axis_extent("expert") > 1
-        if ep:
-            xs = shard(xs, "expert", None, None)
-        else:
-            xs = shard(xs, None, "batch", None)
-        h1 = jnp.einsum("ecd,edf->ecf", xs, p["w1"])
-        h3 = jnp.einsum("ecd,edf->ecf", xs, p["w3"])
-        hs = jax.nn.silu(h1) * h3
-        hs = shard(hs, "expert" if ep else None, None if ep else "batch", "model")
-        ys = jnp.einsum("ecf,efd->ecd", hs, p["w2"])         # (E, C, D)
-        out = jnp.zeros((T, D), jnp.float32)
-        out = out.at[rows.reshape(-1)].add(
-            (ys * g_blk[..., None]).reshape(-1, D).astype(jnp.float32))
+        C = max(1, int(np.ceil(T * K / E * mo.capacity_factor)))
+    starts = jnp.cumsum(group_sizes) - group_sizes       # (E,)
+    slot_c = jax.lax.broadcasted_iota(jnp.int32, (E, C), 1)
+    src = starts[:, None] + slot_c                       # (E, C) into order
+    valid = slot_c < group_sizes[:, None]
+    src = jnp.minimum(src, T * K - 1)
+    rows = flat_t[order][src]                            # (E, C) token ids
+    g_blk = jnp.where(valid, flat_g[order][src], 0.0)    # (E, C)
+    xs = flat[rows] * valid[..., None].astype(flat.dtype)  # (E, C, D)
+    # expert parallelism when E divides the data axis (the classic MoE
+    # all-to-all appears at the gather/scatter boundary); otherwise the
+    # capacity axis stays data-parallel and expert weights stay FSDP
+    ep = E % max(1, axis_extent("expert")) == 0 and axis_extent("expert") > 1
+    if ep:
+        xs = shard(xs, "expert", None, None)
+    else:
+        xs = shard(xs, None, "batch", None)
+    h1 = jnp.einsum("ecd,edf->ecf", xs, p["w1"])
+    h3 = jnp.einsum("ecd,edf->ecf", xs, p["w3"])
+    hs = jax.nn.silu(h1) * h3
+    hs = shard(hs, "expert" if ep else None, None if ep else "batch", "model")
+    ys = jnp.einsum("ecf,efd->ecd", hs, p["w2"])         # (E, C, D)
+    out = jnp.zeros((T, D), jnp.float32)
+    out = out.at[rows.reshape(-1)].add(
+        (ys * g_blk[..., None]).reshape(-1, D).astype(jnp.float32))
 
     out = out.reshape(B, S, D).astype(x.dtype)
     for sp in p.get("shared", []):

@@ -17,6 +17,7 @@ Design notes (DESIGN.md §5, §7):
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
@@ -344,6 +345,57 @@ def _block_decode(cfg: ModelConfig, lp: Params, x, cache: Params, pos, window,
     return x + mlp_out, new_cache
 
 
+def _decode_layers(cfg: ModelConfig, params: Params, caches: list[Params],
+                   tokens, luts, width_map, block):
+    """The layer loop under both decode steps: embed ``tokens``, run
+    ``block(lp, x, cache, window=, lut=)`` on each layer, then the head."""
+    win = window_schedule(cfg)
+    luts_ = luts if cfg.approx_mlp else None
+    if any(isinstance(v, np.ndarray) for v in jax.tree.leaves(luts_)):
+        # a host numpy table would be traced as a compile-time constant and
+        # every plan swap would silently rebuild the executable
+        raise TypeError(
+            "decode step luts must be a jax array passed as a jit argument, "
+            "not a numpy constant (serving hot-swap relies on this)"
+        )
+    group_pos: list[int] | None = None
+    if isinstance(luts_, dict):
+        if width_map is None or len(width_map) != cfg.n_layers:
+            raise ValueError(
+                f"a mixed-width luts dict needs a width_map with one entry "
+                f"per layer (got {width_map!r} for {cfg.n_layers} layers)"
+            )
+        # layer i's row within its width group = how many earlier layers
+        # share its width (group stacks are packed in layer order)
+        group_pos = [width_map[:i].count(width_map[i])
+                     for i in range(cfg.n_layers)]
+    x = params["embed"][tokens].astype(cfg.jnp_dtype)
+    x = shard(x, "batch", None, None)
+    new_caches: list[Params] = []
+    layer_params = [
+        jax.tree.map(lambda a, i=i: a[i], params["layers"])
+        for i in range(cfg.n_layers)
+    ]
+    for i, (lp, cache) in enumerate(zip(layer_params, caches)):
+        if isinstance(win, np.ndarray):
+            w = int(win[i])
+            w = None if w < 0 else w
+        else:
+            w = win
+        lut_i = None
+        if isinstance(luts_, dict):
+            lut_i = luts_[width_map[i]][group_pos[i]]
+        elif luts_ is not None:
+            lut_i = luts_[i] if jnp.ndim(luts_) == 3 else luts_
+        x, nc = block(lp, x, cache, window=w, lut=lut_i)
+        new_caches.append(nc)
+    with jax.named_scope("head"):
+        x = L.block_norm(cfg, params, "ln_f", x)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
+    return logits, new_caches
+
+
 def decode_step(
     cfg: ModelConfig,
     params: Params,
@@ -382,51 +434,8 @@ def decode_step(
     between batches by passing a different stack to the same traced
     executable, which only works if tracing never baked the table in.
     """
-    win = window_schedule(cfg)
-    luts_ = luts if cfg.approx_mlp else None
-    leaves = luts_.values() if isinstance(luts_, dict) else (luts_,)
-    if any(isinstance(v, np.ndarray) for v in leaves):
-        # a host numpy table would be traced as a compile-time constant and
-        # every plan swap would silently rebuild the executable
-        raise TypeError(
-            "decode_step luts must be a jax array passed as a jit argument, "
-            "not a numpy constant (serving hot-swap relies on this)"
-        )
-    group_pos: list[int] | None = None
-    if isinstance(luts_, dict):
-        if width_map is None or len(width_map) != cfg.n_layers:
-            raise ValueError(
-                f"a mixed-width luts dict needs a width_map with one entry "
-                f"per layer (got {width_map!r} for {cfg.n_layers} layers)"
-            )
-        # layer i's row within its width group = how many earlier layers
-        # share its width (group stacks are packed in layer order)
-        group_pos = [width_map[:i].count(width_map[i])
-                     for i in range(cfg.n_layers)]
-    x = params["embed"][tokens].astype(cfg.jnp_dtype)
-    x = shard(x, "batch", None, None)
-    new_caches: list[Params] = []
-    layer_params = [
-        jax.tree.map(lambda a, i=i: a[i], params["layers"])
-        for i in range(cfg.n_layers)
-    ]
-    for i, (lp, cache) in enumerate(zip(layer_params, caches)):
-        if isinstance(win, np.ndarray):
-            w = int(win[i])
-            w = None if w < 0 else w
-        else:
-            w = win
-        lut_i = None
-        if isinstance(luts_, dict):
-            lut_i = luts_[width_map[i]][group_pos[i]]
-        elif luts_ is not None:
-            lut_i = luts_[i] if jnp.ndim(luts_) == 3 else luts_
-        x, nc = _block_decode(cfg, lp, x, cache, pos, w, lut_i)
-        new_caches.append(nc)
-    x = L.block_norm(cfg, params, "ln_f", x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
-    return logits, new_caches
+    return _decode_layers(cfg, params, caches, tokens, luts, width_map,
+                          partial(_block_decode, cfg, pos=pos))
 
 
 # ---------------------------------------------------------------------------
@@ -477,56 +486,15 @@ def decode_step_paged(
 
     This is :func:`decode_step` with the batch-shared scalar ``pos``
     replaced by per-slot vectors and the dense global-attention caches
-    replaced by page pools (:func:`init_paged_caches`); the LUT-stack
-    contract is identical — ``luts`` rides as a jitted argument (same
-    TypeError guard), width maps are trace structure, and all shapes are
-    fixed by ``(max_slots, pages_per_slot, page_size)``, so requests
-    joining and leaving the running batch never retrace.
+    replaced by page pools (:func:`init_paged_caches`); both run one layer
+    loop, so the LUT-stack contract is the same, and all shapes are fixed
+    by ``(max_slots, pages_per_slot, page_size)``, so requests joining and
+    leaving the running batch never retrace.
 
     Named scopes label the step's operations for a profiler trace:
     ``attention`` (projections, the paged KV write and gather, the output
     projection), ``mlp`` (with ``quantize`` inside
     :func:`repro.quant.approx_linear`) and ``head`` (final norm, logits)."""
-    win = window_schedule(cfg)
-    luts_ = luts if cfg.approx_mlp else None
-    leaves = luts_.values() if isinstance(luts_, dict) else (luts_,)
-    if any(isinstance(v, np.ndarray) for v in leaves):
-        raise TypeError(
-            "decode_step_paged luts must be a jax array passed as a jit "
-            "argument, not a numpy constant (serving hot-swap relies on this)"
-        )
-    group_pos: list[int] | None = None
-    if isinstance(luts_, dict):
-        if width_map is None or len(width_map) != cfg.n_layers:
-            raise ValueError(
-                f"a mixed-width luts dict needs a width_map with one entry "
-                f"per layer (got {width_map!r} for {cfg.n_layers} layers)"
-            )
-        group_pos = [width_map[:i].count(width_map[i])
-                     for i in range(cfg.n_layers)]
-    x = params["embed"][tokens].astype(cfg.jnp_dtype)
-    x = shard(x, "batch", None, None)
-    new_caches: list[Params] = []
-    layer_params = [
-        jax.tree.map(lambda a, i=i: a[i], params["layers"])
-        for i in range(cfg.n_layers)
-    ]
-    for i, (lp, cache) in enumerate(zip(layer_params, caches)):
-        if isinstance(win, np.ndarray):
-            w = int(win[i])
-            w = None if w < 0 else w
-        else:
-            w = win
-        lut_i = None
-        if isinstance(luts_, dict):
-            lut_i = luts_[width_map[i]][group_pos[i]]
-        elif luts_ is not None:
-            lut_i = luts_[i] if jnp.ndim(luts_) == 3 else luts_
-        x, nc = _block_decode_paged(cfg, lp, x, cache, pos, tables, active,
-                                    w, lut_i)
-        new_caches.append(nc)
-    with jax.named_scope("head"):
-        x = L.block_norm(cfg, params, "ln_f", x)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)[:, 0]
-    return logits, new_caches
+    return _decode_layers(cfg, params, caches, tokens, luts, width_map,
+                          partial(_block_decode_paged, cfg, pos=pos,
+                                  tables=tables, active=active))

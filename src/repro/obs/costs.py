@@ -61,7 +61,7 @@ def mlp_macs_per_layer(cfg) -> list[int]:
     d = int(cfg.d_model)
     if getattr(cfg, "moe", None) is not None:
         # only the always-on shared experts ride ffn(..., lut); the
-        # sorted top-k dispatch runs exact ragged/batched matmuls
+        # sorted top-k dispatch runs exact batched matmuls
         per = int(cfg.moe.n_shared) * 3 * d * int(cfg.moe.d_ff_expert)
     elif getattr(cfg, "encoder", None) is not None:
         per = 2 * d * int(cfg.d_ff)        # GELU FFN: w1, w2

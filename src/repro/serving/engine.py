@@ -41,7 +41,7 @@ import numpy as np
 from ..kernels.approx_matmul import takes_hi_pass
 from ..library.qos import (LayerPlan, plan_layer_areas, refresh_plan,
                            stack_luts, validate_lut_stack)
-from ..models import decode_fn, init_caches
+from ..models import decode_fn, decode_paged_fn, init_caches
 from ..obs.trace import current_tracer
 from ..obs.trace import event as trace_event
 from ..obs.trace import span as trace_span
@@ -91,6 +91,12 @@ class BatchStats:
         return self.prefill_tokens / self.prefill_s if self.prefill_s else 0.0
 
 
+def _on_device(stack):
+    """A LUT stack, or a mixed-width dict of stacks, as device arrays."""
+    return ({b: jnp.asarray(a) for b, a in stack.items()}
+            if isinstance(stack, dict) else jnp.asarray(stack))
+
+
 def wide_lut_layers(stack) -> int:
     """How many layers of a LUT stack (or of each stack of a mixed-width
     dict) carry a table for which the LUT kernel runs its second pass."""
@@ -99,6 +105,9 @@ def wide_lut_layers(stack) -> int:
 
 
 class ServingEngine:
+    # the decode step the engine jits; the continuous engine names its own
+    _decode_fn = staticmethod(decode_fn)
+
     def __init__(
         self,
         cfg,
@@ -158,6 +167,10 @@ class ServingEngine:
         # plan_id so the per-step cost attribution is a dict lookup
         self._cost_rows: dict[str, dict] = {}
         self._macs_per_layer = None
+        # device-resident class stacks, keyed by ladder level and dropped
+        # when the ladder object changes (see _resolve_stack)
+        self._stacks: dict[int, object] = {}
+        self._stacks_ladder = None
 
         if self._adaptive:
             assert cfg.approx_mlp, (
@@ -204,26 +217,24 @@ class ServingEngine:
         self._jit_step = jax.jit(self._make_step_fn(), donate_argnums=(1,))
 
     def _make_step_fn(self):
-        """Build the closure the engine jits exactly once.  Subclasses
-        (the continuous-batching engine) override this to route through a
-        different decode step; everything else — LUT stacking, swap
-        validation, watcher refresh — is shared."""
-        step = decode_fn(self.cfg)
+        """Build the closure the engine jits exactly once:
+        ``step_fn(params, caches, *inputs, luts)`` runs the engine's
+        decode function on the inputs with the plan's LUT stack.  An
+        engine without a plan passes ``luts=None``, an empty pytree, so
+        its program holds no table argument."""
+        step = self._decode_fn(self.cfg)
         cfg, wm = self.cfg, self._width_map
-        if self._adaptive:
-            def step_fn(params, caches, tok, pos, luts):
-                # python side effect runs once per *trace*, so this counts
-                # compilations, not calls — the no-retrace-across-swaps
-                # invariant is `trace_count == 1` after any number of swaps
-                self._trace_count += 1
-                if wm is not None:
-                    return step(cfg, params, caches, tok, pos, luts=luts,
-                                width_map=wm)
-                return step(cfg, params, caches, tok, pos, luts=luts)
-        else:
-            def step_fn(params, caches, tok, pos):
-                self._trace_count += 1
-                return step(cfg, params, caches, tok, pos)
+
+        def step_fn(params, caches, *args):
+            # python side effect runs once per *trace*, so this counts
+            # compilations, not calls — the no-retrace-across-swaps
+            # invariant is `trace_count == 1` after any number of swaps
+            self._trace_count += 1
+            *inputs, luts = args
+            if luts is None:   # the encoder-decoder step takes no tables
+                return step(cfg, params, caches, *inputs)
+            return step(cfg, params, caches, *inputs, luts=luts,
+                        width_map=wm)
         return step_fn
 
     # ----------------------------------------------------------------- state
@@ -244,10 +255,8 @@ class ServingEngine:
         return self._plan
 
     def _step(self, caches, tok, pos, luts=None):
-        if self._adaptive:
-            return self._jit_step(self.params, caches, tok, pos,
-                                  self._luts if luts is None else luts)
-        return self._jit_step(self.params, caches, tok, pos)
+        return self._jit_step(self.params, caches, tok, pos,
+                              self._luts if luts is None else luts)
 
     # ------------------------------------------------------------------ swap
     def swap_plan(self, plan: LayerPlan, stack, *, reason: str = "manual",
@@ -260,8 +269,7 @@ class ServingEngine:
         assert self._adaptive, "engine was built without a QoS plan"
         if plan.plan_id == self._plan.plan_id:
             return False
-        new = (dict((b, jnp.asarray(a)) for b, a in stack.items())
-               if isinstance(stack, dict) else jnp.asarray(stack))
+        new = _on_device(stack)
         validate_lut_stack(self._luts, new)
         old_id = self._plan.plan_id
         self._plan, self._luts = plan, new
@@ -278,6 +286,21 @@ class ServingEngine:
                                     event_id=eid, reason=reason,
                                     old=old_id, new=plan.plan_id)
         return True
+
+    def refresh(self, frontier, *, controller=None, scheduler=None,
+                telemetry: Telemetry | None = None,
+                batch_idx: int = 0) -> bool:
+        """Adopt a refreshed frontier in the form
+        :meth:`~repro.serving.watcher.LibraryWatcher.load_frontier` gives
+        it: a :class:`~repro.precision.plans.MixedFrontier` for a
+        mixed-width engine (:meth:`refresh_mixed`), else ``(compiled,
+        exact_area, bits)`` (:meth:`refresh_library`)."""
+        kw = dict(controller=controller, scheduler=scheduler,
+                  telemetry=telemetry, batch_idx=batch_idx)
+        if self._width_map is not None:
+            return self.refresh_mixed(frontier, **kw)
+        compiled, exact_area, _bits = frontier
+        return self.refresh_library(compiled, exact_area, **kw)
 
     def refresh_library(self, compiled, exact_area: float, *,
                         controller=None, scheduler=None,
@@ -406,6 +429,117 @@ class ServingEngine:
                          else self._mae_by_key.get(c.key, 0.0)
                          for c in plan.choices])
 
+    def _resolve_stack(self, active_classes):
+        """The LUT stack of a batch or step: with a scheduler, it decodes
+        at the level of its *strictest* active class (slots share one
+        step, so the most exacting tenant sets the table for everyone in
+        it — per-class plans separate again at the router's replica
+        level).  Returns ``(luts, plan, global_level, step_level)`` — the
+        last is the level the step actually decodes at, which the
+        provenance ledger records per token range.  The stack comes from
+        a device-resident cache per ladder level, cleared when the ladder
+        object changes (a refresh): without it every class batch or step
+        would re-upload its ``(n_layers, side, side)`` stack."""
+        if not self._adaptive:
+            return None, None, None, None
+        if self._scheduler is None:
+            lvl = self._controller.level if self._controller else None
+            return None, self._plan, lvl, lvl
+        sch = self._scheduler
+        glevel = (self._controller.level if self._controller is not None
+                  else sch.top_level)
+        level = min((sch.level_for(c, glevel) for c in active_classes),
+                    default=min(glevel, sch.top_level))
+        if sch.ladder is not self._stacks_ladder:
+            self._stacks.clear()
+            self._stacks_ladder = sch.ladder
+        luts = self._stacks.get(level)
+        if luts is None:
+            luts = self._stacks[level] = _on_device(sch.ladder.luts(level))
+        plan = sch.ladder.plan(level)
+        self.telemetry.register_plan(plan)
+        return luts, plan, glevel, level
+
+    # --------------------------------------------------------- control plane
+    def _bind(self, *, telemetry, controller, watcher, scheduler, online,
+              health, log) -> Telemetry:
+        """Bind the control plane a serve runs under."""
+        if scheduler is not None:
+            assert self._adaptive, "class-aware serving needs a QoS plan"
+        self.telemetry = telemetry or Telemetry()
+        self._controller, self._watcher = controller, watcher
+        self._scheduler, self._online, self._log = scheduler, online, log
+        self._health = health
+        if self._adaptive:
+            self.telemetry.register_plan(self._plan)
+        return self.telemetry
+
+    def _control_plane(self, idx: int, unit: str, load_ms: float,
+                       drift: Callable[[], float | None], glevel) -> None:
+        """What runs after batch or step ``idx`` (``unit`` names which):
+        the watcher poll and library refresh, then the controller, fed the
+        loop's load signal ``load_ms`` and ``drift()``.  ``drift`` is read
+        after the refresh and gives the measured drift only when it speaks
+        for the global level ``glevel``, else ``None``."""
+        if not self._adaptive:
+            return
+        controller, scheduler = self._controller, self._scheduler
+        health, log = self._health, self._log
+        if self._watcher is not None and self._watcher.poll():
+            try:
+                # LookupError: store emptied; ValueError: refreshed stack
+                # would retrace (validate_lut_stack refused).  Either way
+                # the server keeps running on the old, still-consistent
+                # plan.
+                changed = self.refresh(
+                    self._watcher.load_frontier(), controller=controller,
+                    scheduler=scheduler, telemetry=self.telemetry,
+                    batch_idx=idx)
+                eid = trace_event("serve.refresh", cause="watcher",
+                                  changed=changed, batch=idx)
+                if health is not None:
+                    health.note_event("serve.refresh", step=idx,
+                                      event_id=eid, changed=changed)
+                if changed and log:
+                    log(f"{unit} {idx}: library refresh -> "
+                        f"plan {self._plan.plan_id}")
+            except (LookupError, ValueError) as e:
+                trace_event("serve.refresh", cause="watcher", changed=False,
+                            batch=idx, skipped=str(e))
+                if log:
+                    log(f"watcher: refresh skipped ({e})")
+        if controller is None:
+            return
+        level = controller.observe(load_ms, drift())
+        if level is None:
+            return
+        reason = controller.last_reason
+        eid = trace_event("serve.control", level=level, cause=reason,
+                          batch=idx)
+        if health is not None:
+            health.note_event("serve.control", step=idx, event_id=eid,
+                              level=level, cause=reason)
+        if scheduler is None:
+            moved = self.swap_plan(controller.plan, controller.luts(),
+                                   reason=f"qos-{reason}",
+                                   telemetry=self.telemetry, batch_idx=idx)
+            if moved and log:
+                log(f"{unit} {idx}: controller -> level {level} ({reason}), "
+                    f"plan {self._plan.plan_id}")
+        else:
+            # the global operating point moved; per-class stacks resolve
+            # against it at their next batch or step.  glevel was read
+            # before a possible ladder refresh above — clamp both levels to
+            # the ladder the swap log points at
+            lad = scheduler.ladder
+            self.telemetry.record_swap(
+                batch=idx, reason=f"qos-{reason}",
+                old=lad.plan(min(glevel, len(lad) - 1)).plan_id,
+                new=lad.plan(min(level, len(lad) - 1)).plan_id)
+            if log:
+                log(f"{unit} {idx}: controller -> global level {level} "
+                    f"({reason})")
+
     # ----------------------------------------------------------------- batch
     def run_batch(self, requests: list[Request], *,
                   shadow: bool = False, luts=None) -> BatchStats:
@@ -418,8 +552,7 @@ class ServingEngine:
         stack (same shapes, so still the one trace)."""
         assert 0 < len(requests) <= self.batch
         if luts is not None:
-            luts = (dict((b, jnp.asarray(a)) for b, a in luts.items())
-                    if isinstance(luts, dict) else jnp.asarray(luts))
+            luts = _on_device(luts)
         prompts_np = np.zeros((self.batch, self.prompt_len), np.int32)
         for i, r in enumerate(requests):
             # heterogeneous prompt lengths zero-pad to the fixed geometry:
@@ -531,12 +664,9 @@ class ServingEngine:
         store mid-serve)."""
         assert profile.prompt_len == self.prompt_len
         assert profile.gen_len == self.gen_len
-        if scheduler is not None:
-            assert self._adaptive, "class-aware serving needs a QoS plan"
-        telemetry = telemetry or Telemetry()
-        self._health = health
-        if self._adaptive:
-            telemetry.register_plan(self._plan)
+        telemetry = self._bind(telemetry=telemetry, controller=controller,
+                               watcher=watcher, scheduler=scheduler,
+                               online=online, health=health, log=log)
         per_tick = synth_requests(profile, self.cfg.vocab_size, seed)
         queue: deque[Request] = deque()
         queues: dict[str, deque[Request]] | None = None
@@ -546,11 +676,6 @@ class ServingEngine:
         # synthetic arrival tick) so drained batches can report real
         # time-in-queue to the per-class wait histograms
         enqueued_at: dict[int, float] = {}
-        # device-resident class stacks, keyed by ladder level and
-        # invalidated on ladder refresh — without this every class batch
-        # would re-upload its (n_layers, side, side) stack host-to-device
-        device_stacks: dict[int, object] = {}
-        device_ladder = None
         batch_idx = 0
         for tick in range(profile.n_ticks):
             now = time.perf_counter()
@@ -583,22 +708,8 @@ class ServingEngine:
 
                 # ---- resolve this batch's plan --------------------------
                 if scheduler is not None:
-                    glevel = (controller.level if controller is not None
-                              else scheduler.top_level)
-                    level_c = scheduler.level_for(cls, glevel)
-                    plan_b = scheduler.ladder.plan(level_c)
-                    if scheduler.ladder is not device_ladder:
-                        device_stacks.clear()
-                        device_ladder = scheduler.ladder
-                    luts_b = device_stacks.get(level_c)
-                    if luts_b is None:
-                        raw = scheduler.ladder.luts(level_c)
-                        luts_b = (dict((b, jnp.asarray(a))
-                                       for b, a in raw.items())
-                                  if isinstance(raw, dict)
-                                  else jnp.asarray(raw))
-                        device_stacks[level_c] = luts_b
-                    telemetry.register_plan(plan_b)
+                    luts_b, plan_b, glevel, level_c = self._resolve_stack(
+                        [cls])
                 else:
                     glevel = level_c = None
                     plan_b, luts_b = self._plan, None
@@ -637,92 +748,22 @@ class ServingEngine:
                                      if scheduler is not None else None))
 
                 # ---- between-batch control plane ------------------------
-                if watcher is not None and self._adaptive and watcher.poll():
-                    try:
-                        fr = watcher.load_frontier()
-                        # LookupError: store emptied; ValueError: refreshed
-                        # stack would retrace (validate_lut_stack refused).
-                        # Either way the server keeps running on the old,
-                        # still-consistent plan.
-                        if self._width_map is not None:
-                            changed = self.refresh_mixed(
-                                fr, controller=controller,
-                                scheduler=scheduler, telemetry=telemetry,
-                                batch_idx=batch_idx)
-                        else:
-                            compiled, exact_area, _bits = fr
-                            changed = self.refresh_library(
-                                compiled, exact_area, controller=controller,
-                                scheduler=scheduler, telemetry=telemetry,
-                                batch_idx=batch_idx)
-                        eid = trace_event("serve.refresh", cause="watcher",
-                                          changed=changed, batch=batch_idx)
-                        if health is not None:
-                            health.note_event("serve.refresh",
-                                              step=batch_idx, event_id=eid,
-                                              changed=changed)
-                        if changed and log:
-                            log(f"batch {batch_idx}: library refresh -> "
-                                f"plan {self._plan.plan_id}")
-                    except (LookupError, ValueError) as e:
-                        trace_event("serve.refresh", cause="watcher",
-                                    changed=False, batch=batch_idx,
-                                    skipped=str(e))
-                        if log:
-                            log(f"watcher: refresh skipped ({e})")
-                if controller is not None and self._adaptive:
+                self._control_plane(
+                    batch_idx, "batch",
                     # the load signal is *effective* ms/step: service time
                     # scaled by outstanding work (Little's-law flavour) —
                     # raw step latency is nearly plan-independent, so a
                     # building queue, not the step clock, is what says
                     # "trade accuracy for throughput" under ramp/spike load
-                    eff_ms = effective_load_ms(stats.ms_per_step,
-                                               backlog=backlog,
-                                               capacity=self.batch)
+                    effective_load_ms(stats.ms_per_step, backlog=backlog,
+                                      capacity=self.batch),
                     # with classes, the batch may have decoded below the
                     # global level (its class cap) — its drift then says
                     # nothing about the global operating point
-                    drift_sig = (stats.drift
-                                 if scheduler is None or level_c == glevel
-                                 else None)
-                    level = controller.observe(eff_ms, drift_sig)
-                    if level is not None:
-                        eid = trace_event("serve.control", level=level,
-                                          cause=controller.last_reason,
-                                          batch=batch_idx)
-                        if health is not None:
-                            health.note_event("serve.control",
-                                              step=batch_idx, event_id=eid,
-                                              level=level,
-                                              cause=controller.last_reason)
-                        if scheduler is None:
-                            moved = self.swap_plan(
-                                controller.plan, controller.luts(),
-                                reason=f"qos-{controller.last_reason}",
-                                telemetry=telemetry, batch_idx=batch_idx)
-                            if moved and log:
-                                log(f"batch {batch_idx}: controller -> "
-                                    f"level {level} "
-                                    f"({controller.last_reason}), plan "
-                                    f"{self._plan.plan_id}")
-                        else:
-                            # the global operating point moved; per-class
-                            # stacks resolve against it at their next
-                            # batch.  glevel was read before a possible
-                            # mid-iteration ladder refresh — clamp both
-                            # levels to the ladder the swap log points at
-                            lad = scheduler.ladder
-                            telemetry.record_swap(
-                                batch=batch_idx,
-                                reason=f"qos-{controller.last_reason}",
-                                old=lad.plan(min(glevel,
-                                                 len(lad) - 1)).plan_id,
-                                new=lad.plan(min(level,
-                                                 len(lad) - 1)).plan_id)
-                            if log:
-                                log(f"batch {batch_idx}: controller -> "
-                                    f"global level {level} "
-                                    f"({controller.last_reason})")
+                    lambda: (stats.drift
+                             if scheduler is None or level_c == glevel
+                             else None),
+                    glevel)
                 if on_batch_end is not None:
                     on_batch_end(self, batch_idx)
                 batch_idx += 1
@@ -756,6 +797,7 @@ class ContinuousServingEngine(ServingEngine):
     strictly by priority.
     """
 
+    _decode_fn = staticmethod(decode_paged_fn)
     # class-level defaults so the provenance/cost bookkeeping helpers stay
     # drivable on a bare instance (tests exercise them without __init__)
     replica_name = ""
@@ -792,25 +834,6 @@ class ContinuousServingEngine(ServingEngine):
                          gen_len=gen_len, **kw)
         self._started = False
         self._last_step_args = None   # what step_once last passed the step
-
-    def _make_step_fn(self):
-        from ..models import decode_paged_fn
-
-        pstep = decode_paged_fn(self.cfg)
-        cfg, wm = self.cfg, self._width_map
-        if self._adaptive:
-            def step_fn(params, caches, tok, pos, active, tables, luts):
-                self._trace_count += 1
-                if wm is not None:
-                    return pstep(cfg, params, caches, tok, pos, active,
-                                 tables, luts=luts, width_map=wm)
-                return pstep(cfg, params, caches, tok, pos, active, tables,
-                             luts=luts)
-        else:
-            def step_fn(params, caches, tok, pos, active, tables):
-                self._trace_count += 1
-                return pstep(cfg, params, caches, tok, pos, active, tables)
-        return step_fn
 
     def lowered_step(self):
         """The decode step lowered (not compiled) at the types of the
@@ -861,12 +884,9 @@ class ContinuousServingEngine(ServingEngine):
         from .kvcache import PageAllocator
         from .slots import SlotPool, WeightedFairQueues
 
-        if scheduler is not None:
-            assert self._adaptive, "class-aware serving needs a QoS plan"
-        self.telemetry = telemetry or Telemetry()
-        self._controller, self._watcher = controller, watcher
-        self._scheduler, self._online, self._log = scheduler, online, log
-        self._health = health
+        self._bind(telemetry=telemetry, controller=controller,
+                   watcher=watcher, scheduler=scheduler, online=online,
+                   health=health, log=log)
         if shadow_every is not None:
             self._shadow_every = max(1, int(shadow_every))
         elif controller is not None:
@@ -885,8 +905,6 @@ class ContinuousServingEngine(ServingEngine):
                 scheduler.book.names, scheduler.book.drain_weights())
         else:
             self._queues = WeightedFairQueues(("std",))
-        self._device_stacks: dict[int, object] = {}
-        self._device_ladder = None
         self._step_idx = 0
         self._tick = 0
         self._n_preemptions = 0
@@ -915,8 +933,6 @@ class ContinuousServingEngine(ServingEngine):
         if self._provenance is not None and self._macs_per_layer is not None:
             self._provenance.note_model(name=self.cfg.name,
                                         macs=self._macs_per_layer)
-        if self._adaptive:
-            self.telemetry.register_plan(self._plan)
         self._started = True
         return self.telemetry
 
@@ -1119,37 +1135,6 @@ class ContinuousServingEngine(ServingEngine):
         return row
 
     # ------------------------------------------------------------------ step
-    def _resolve_stack(self, active_classes):
-        """The step's LUT stack: with a scheduler, the batch decodes at
-        the level of its *strictest* active class (slots share one step,
-        so the most exacting tenant sets the table for everyone in it —
-        per-class plans separate again at the router's replica level).
-        Returns ``(luts, plan, global_level, step_level)`` — the last is
-        the level this step actually decodes at, which the provenance
-        ledger records per token range."""
-        if not self._adaptive:
-            return None, None, None, None
-        if self._scheduler is None:
-            lvl = self._controller.level if self._controller else None
-            return None, self._plan, lvl, lvl
-        sch = self._scheduler
-        glevel = (self._controller.level if self._controller is not None
-                  else sch.top_level)
-        level = min((sch.level_for(c, glevel) for c in active_classes),
-                    default=min(glevel, sch.top_level))
-        if sch.ladder is not self._device_ladder:
-            self._device_stacks.clear()
-            self._device_ladder = sch.ladder
-        luts = self._device_stacks.get(level)
-        if luts is None:
-            raw = sch.ladder.luts(level)
-            luts = (dict((b, jnp.asarray(a)) for b, a in raw.items())
-                    if isinstance(raw, dict) else jnp.asarray(raw))
-            self._device_stacks[level] = luts
-        plan = sch.ladder.plan(level)
-        self.telemetry.register_plan(plan)
-        return luts, plan, glevel, level
-
     def step_once(self, now: float | None = None) -> bool:
         """Admit what fits, then run one decode step over the pool.
         Returns ``False`` (and runs nothing) when no slot is active.
@@ -1214,9 +1199,8 @@ class ContinuousServingEngine(ServingEngine):
                     # from a real one to the telemetry, the SLO monitors
                     # and the detectors
                     time.sleep(self.inject_step_delay)
-                self._last_step_args = ((self.params, self._caches, *jt)
-                                        + ((luts,) if self._adaptive
-                                           else ()))
+                self._last_step_args = (self.params, self._caches, *jt,
+                                        luts)
                 logits, self._caches = self._jit_step(*self._last_step_args)
             with trace_span("serve.step.wait"):
                 logits.block_until_ready()
@@ -1326,87 +1310,27 @@ class ContinuousServingEngine(ServingEngine):
                 class_state=(self._scheduler.snapshot(glevel)
                              if self._scheduler is not None else None))
 
-        self._control_plane(step_s, drift, plan_b, glevel, backlog, occ)
-        self._step_idx += 1
-        return prefill_tokens
-
-    def _control_plane(self, step_s, drift, plan_b, glevel, backlog, occ):
-        controller, scheduler = self._controller, self._scheduler
+        scheduler = self._scheduler
         if drift is not None and self._adaptive:
             if scheduler is not None:
                 for cls in {seq.cls for _, seq in self._pool}:
                     scheduler.observe(cls, drift)
             if self._online is not None and plan_b is not None:
                 self._online.update(self._plan_maes(plan_b), drift)
-        if self._watcher is not None and self._adaptive \
-                and self._watcher.poll():
-            try:
-                fr = self._watcher.load_frontier()
-                if self._width_map is not None:
-                    changed = self.refresh_mixed(
-                        fr, controller=controller, scheduler=scheduler,
-                        telemetry=self.telemetry, batch_idx=self._step_idx)
-                else:
-                    compiled, exact_area, _bits = fr
-                    changed = self.refresh_library(
-                        compiled, exact_area, controller=controller,
-                        scheduler=scheduler, telemetry=self.telemetry,
-                        batch_idx=self._step_idx)
-                eid = trace_event("serve.refresh", cause="watcher",
-                                  changed=changed, batch=self._step_idx)
-                if self._health is not None:
-                    self._health.note_event("serve.refresh",
-                                            step=self._step_idx,
-                                            event_id=eid, changed=changed)
-                if changed and self._log:
-                    self._log(f"step {self._step_idx}: library refresh -> "
-                              f"plan {self._plan.plan_id}")
-            except (LookupError, ValueError) as e:
-                trace_event("serve.refresh", cause="watcher", changed=False,
-                            batch=self._step_idx, skipped=str(e))
-                if self._log:
-                    self._log(f"watcher: refresh skipped ({e})")
-        if controller is not None and self._adaptive:
+        self._control_plane(
+            self._step_idx, "step",
             # occupancy replaces the fixed loop's whole-queue heuristic:
             # requests already in slots are being served, only true
             # admission-queue depth counts as waiting work
-            eff_ms = effective_load_ms(1e3 * step_s, backlog=backlog,
-                                       capacity=self.max_slots,
-                                       occupancy=occ)
-            drift_sig = (drift if scheduler is None
-                         or (glevel is not None
-                             and plan_b is scheduler.ladder.plan(glevel))
-                         else None)
-            level = controller.observe(eff_ms, drift_sig)
-            if level is not None:
-                eid = trace_event("serve.control", level=level,
-                                  cause=controller.last_reason,
-                                  batch=self._step_idx)
-                if self._health is not None:
-                    self._health.note_event("serve.control",
-                                            step=self._step_idx,
-                                            event_id=eid, level=level,
-                                            cause=controller.last_reason)
-                if scheduler is None:
-                    moved = self.swap_plan(
-                        controller.plan, controller.luts(),
-                        reason=f"qos-{controller.last_reason}",
-                        telemetry=self.telemetry, batch_idx=self._step_idx)
-                    if moved and self._log:
-                        self._log(f"step {self._step_idx}: controller -> "
-                                  f"level {level} "
-                                  f"({controller.last_reason})")
-                else:
-                    lad = scheduler.ladder
-                    self.telemetry.record_swap(
-                        batch=self._step_idx,
-                        reason=f"qos-{controller.last_reason}",
-                        old=lad.plan(min(glevel, len(lad) - 1)).plan_id,
-                        new=lad.plan(min(level, len(lad) - 1)).plan_id)
-                    if self._log:
-                        self._log(f"step {self._step_idx}: controller -> "
-                                  f"global level {level} "
-                                  f"({controller.last_reason})")
+            effective_load_ms(1e3 * step_s, backlog=backlog,
+                              capacity=self.max_slots, occupancy=occ),
+            lambda: (drift if scheduler is None
+                     or (glevel is not None
+                         and plan_b is scheduler.ladder.plan(glevel))
+                     else None),
+            glevel)
+        self._step_idx += 1
+        return prefill_tokens
 
     # ----------------------------------------------------------------- serve
     def serve(self, profile: LoadProfile, *, controller=None, watcher=None,

@@ -124,15 +124,9 @@ class ReplicaRouter:
             if r.engine.plan is None:
                 continue
             try:
-                if r.engine._width_map is not None:
-                    changed = r.engine.refresh_mixed(
-                        fr, controller=r.controller, scheduler=r.scheduler,
-                        telemetry=r.telemetry)
-                else:
-                    compiled, exact_area, _bits = fr
-                    changed = r.engine.refresh_library(
-                        compiled, exact_area, controller=r.controller,
-                        scheduler=r.scheduler, telemetry=r.telemetry)
+                changed = r.engine.refresh(
+                    fr, controller=r.controller, scheduler=r.scheduler,
+                    telemetry=r.telemetry)
                 trace_event("router.refresh", replica=r.name,
                             changed=changed)
             except (LookupError, ValueError) as e:

@@ -321,11 +321,14 @@ def test_telemetry_ring_bounds_and_summary(two_op_library):
 # ---------------------------------------------------------------------------
 # end-to-end: adaptive serve with controller + watcher hot-swaps, one trace
 # ---------------------------------------------------------------------------
-def test_e2e_adaptive_serve_hot_swaps_without_retrace(tmp_path):
+@pytest.mark.parametrize("kind", ["fixed", "continuous"])
+def test_e2e_adaptive_serve_hot_swaps_without_retrace(tmp_path, kind):
+    """Both engines' own control plane: the watcher's library refresh and
+    the controller's level moves swap plans under one decode trace."""
     jax = pytest.importorskip("jax")
     from repro.configs import get_config
     from repro.models import init_model
-    from repro.serving import ServingEngine
+    from repro.serving import ContinuousServingEngine, ServingEngine
 
     lib = tmp_path / "lib"
     store = fill_library(lib, [benchmark("mul_i4"), trunc_mul2()])
@@ -345,18 +348,28 @@ def test_e2e_adaptive_serve_hot_swaps_without_retrace(tmp_path):
         shadow_every=1, ewma_alpha=1.0))
     watcher = LibraryWatcher(lib, min_poll_s=0.0)
 
-    def densify_midrun(engine, batch_idx):
-        if batch_idx == 2:   # a "background fleet sweep" lands a cheaper op
+    def densify_midrun(engine, idx):
+        if idx == 2:   # a "background fleet sweep" lands a cheaper op
             circ = zero_mul2()
             store.put_circuit(circ, OperatorSignature("mul", 2, "wce", 9),
                               area=area(circ), source="fleet")
 
-    engine = ServingEngine(cfg, params, batch=2, prompt_len=4, gen_len=4,
-                           plan=ladder.plan(0), compiled=compiled,
-                           exact_area=exact_area)
+    plan_kw = dict(plan=ladder.plan(0), compiled=compiled,
+                   exact_area=exact_area)
     profile = steady(6, 2, prompt_len=4, gen_len=4)
-    tel = engine.serve(profile, controller=ctrl, watcher=watcher,
-                       telemetry=Telemetry(), on_batch_end=densify_midrun)
+    if kind == "fixed":
+        engine = ServingEngine(cfg, params, batch=2, prompt_len=4,
+                               gen_len=4, **plan_kw)
+        tel = engine.serve(profile, controller=ctrl, watcher=watcher,
+                           telemetry=Telemetry(),
+                           on_batch_end=densify_midrun)
+    else:
+        engine = ContinuousServingEngine(cfg, params, max_slots=2,
+                                         prompt_len=4, gen_len=4,
+                                         page_size=4, **plan_kw)
+        tel = engine.serve(profile, controller=ctrl, watcher=watcher,
+                           telemetry=Telemetry(),
+                           on_step_end=densify_midrun)
 
     reasons = {s["reason"] for s in tel.swaps}
     assert any(r.startswith("qos-") for r in reasons), tel.swaps
@@ -364,14 +377,16 @@ def test_e2e_adaptive_serve_hot_swaps_without_retrace(tmp_path):
     assert tel.swap_count >= 2
     # the decode step was traced exactly once across every swap
     assert engine.trace_count == 1
+    # drift was sampled against the exact shadow step
+    assert any(e["drift"] is not None for e in tel.events)
     # the serve ended on a cheaper-than-exact plan that includes the
     # mid-run operator (zero_mul2 has area ~0)
     assert engine.plan.total_area < ladder.plan(0).total_area
     keys_used = {c.key for c in engine.plan.choices}
     new_keys = {r.key for r, _ in engine._compiled if r.wce == 9}
     assert keys_used & new_keys, (keys_used, new_keys)
-    # drift was sampled against the exact shadow step
-    assert any(e["drift"] is not None for e in tel.events)
+    if kind == "continuous":
+        return
     s = tel.summary()
     assert s["batches"] == 6 and s["requests"] == 12
     assert s["plans_used"] >= 2
