@@ -29,6 +29,14 @@ its own copy of the kernel's code, so the plane building loops over the
 decode step's 108 calls carried ~2 MB of code; now ~1 MB); the
 contraction stays unrolled, as a loop it ran 1.6x slower on a TPU v5e.
 
+**Operands.**  ``a`` holds int32 codes; ``b`` holds int32 codes or the
+one-byte form the serving engines prepare once per weight
+(:func:`repro.quant.int4.prepare_weight`): uint8 holding the code itself,
+in ``[0, side)``.  Each ``(block_k, block_n)`` block of ``b`` is widened
+to int32 once, after its load, so a uint8 ``b`` reaches the kernel as
+stored — a quarter of the int32 bytes, and no widened copy written in
+HBM.  K padding is code 0 in either dtype.
+
 **Exactness.**  Every contraction is one bf16 MXU pass with f32
 accumulation.  ``B_y`` is 0/1, and bf16 holds every integer up to 256
 exactly, so a table whose entries all lie in ``[0, 255]`` (every exact
@@ -164,7 +172,8 @@ def _kernel(lut_ref, a_ref, b_ref, out_ref, planes_ref, acc_ref, wide_ref,
         def _planes():
             _build_planes(planes_ref, a_ref, lut_ref, nib, parts)
 
-        S = _lut16_contract(planes_ref, _nibbles(b_ref[...], nib),
+        S = _lut16_contract(planes_ref,
+                            _nibbles(b_ref[...].astype(jnp.int32), nib),
                             parts * nib * bm)
         total = None
         for p in range(parts):
@@ -215,7 +224,8 @@ def _extract_tile_jnp(lut: jax.Array) -> jax.Array:
 )
 def approx_matmul_pallas(
     a: jax.Array,    # (M, K) int32 in [0, side)
-    b: jax.Array,    # (K, N) int32 in [0, side)
+    b: jax.Array,    # (K, N) int32 or uint8 in [0, side): the codes as
+                     # stored, widened per (block_k, block_n) block
     lut: jax.Array,  # (side, side) int32; side = 16 (4-bit) or 256 (8-bit)
     *,
     block_m: int = 128,

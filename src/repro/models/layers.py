@@ -72,8 +72,9 @@ def block_norm(cfg: ModelConfig, p: Params, name: str,
     return rmsnorm(x, p[name], cfg.norm_eps)
 
 
-def linear(x: jax.Array, w: jax.Array, lut: jax.Array | None = None) -> jax.Array:
-    """Matmul, optionally routed through the approximate-multiplier LUT."""
+def linear(x: jax.Array, w, lut: jax.Array | None = None) -> jax.Array:
+    """Matmul, optionally routed through the approximate-multiplier LUT;
+    with a LUT, ``w`` may be a :class:`~repro.quant.int4.PreparedWeight`."""
     if lut is not None:
         return approx_linear(x, w, lut)
     return jnp.einsum("...d,df->...f", x, w)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..parallel import shard
+from ..quant.int4 import prepare_weight
 from .config import ModelConfig
 from . import layers as L
 
@@ -345,18 +346,46 @@ def _block_decode(cfg: ModelConfig, lp: Params, x, cache: Params, pos, window,
     return x + mlp_out, new_cache
 
 
+def prepare_mlp_weights(params: Params, widths: int | tuple[int, ...]):
+    """Every dense MLP matrix of ``params`` prepared for the LUT matmul
+    (:func:`repro.quant.int4.prepare_weight`), laid out as the LUT stack
+    a decode step takes beside it: for one width ``bits``,
+    ``{name: PreparedWeight}`` stacked ``(L, K, N)`` per matrix; for a
+    per-layer width map, ``{bits: {name: PreparedWeight}}`` stacked
+    ``(n_group, K, N)`` in layer order.  One jitted call per stacked
+    matrix, on the device.  A model without dense MLP layers (MoE, RWKV)
+    gives ``{}``: its matrices stay per call."""
+    ffn = params["layers"].get("ffn", {})
+    if not ffn:
+        return {}
+    if isinstance(widths, int):
+        return {name: jax.jit(prepare_weight, static_argnums=1)(w, widths)
+                for name, w in ffn.items()}
+    groups = {}
+    for b in sorted(set(widths)):
+        rows = np.array([i for i, wb in enumerate(widths) if wb == b])
+        groups[b] = {name: jax.jit(lambda w, rows=rows, b=b:
+                                   prepare_weight(w[rows], b))(w)
+                     for name, w in ffn.items()}
+    return groups
+
+
 def _decode_layers(cfg: ModelConfig, params: Params, caches: list[Params],
-                   tokens, luts, width_map, block):
+                   tokens, luts, width_map, block, prepared=None):
     """The layer loop under both decode steps: embed ``tokens``, run
-    ``block(lp, x, cache, window=, lut=)`` on each layer, then the head."""
+    ``block(lp, x, cache, window=, lut=)`` on each layer, then the head.
+    ``prepared`` (:func:`prepare_mlp_weights`, laid out as ``luts``)
+    stands in for each layer's float MLP matrices."""
     win = window_schedule(cfg)
     luts_ = luts if cfg.approx_mlp else None
-    if any(isinstance(v, np.ndarray) for v in jax.tree.leaves(luts_)):
+    if any(isinstance(v, np.ndarray)
+           for v in jax.tree.leaves((luts_, prepared))):
         # a host numpy table would be traced as a compile-time constant and
         # every plan swap would silently rebuild the executable
         raise TypeError(
-            "decode step luts must be a jax array passed as a jit argument, "
-            "not a numpy constant (serving hot-swap relies on this)"
+            "decode step luts and prepared weights must be jax arrays "
+            "passed as jit arguments, not numpy constants (serving hot-swap "
+            "relies on this)"
         )
     group_pos: list[int] | None = None
     if isinstance(luts_, dict):
@@ -369,6 +398,18 @@ def _decode_layers(cfg: ModelConfig, params: Params, caches: list[Params],
         # share its width (group stacks are packed in layer order)
         group_pos = [width_map[:i].count(width_map[i])
                      for i in range(cfg.n_layers)]
+
+    def row(stack, i):
+        """Layer ``i``'s entry of a per-layer stack (or pytree of them),
+        or of its width group's in a mixed-width dict."""
+        if group_pos is not None:
+            return jax.tree.map(lambda a: a[group_pos[i]],
+                                stack[width_map[i]])
+        return jax.tree.map(lambda a: a[i], stack)
+
+    # a table per layer (a stack, or a mixed-width dict), or one shared
+    per_layer = group_pos is not None or (luts_ is not None
+                                          and jnp.ndim(luts_) == 3)
     x = params["embed"][tokens].astype(cfg.jnp_dtype)
     x = shard(x, "batch", None, None)
     new_caches: list[Params] = []
@@ -382,11 +423,9 @@ def _decode_layers(cfg: ModelConfig, params: Params, caches: list[Params],
             w = None if w < 0 else w
         else:
             w = win
-        lut_i = None
-        if isinstance(luts_, dict):
-            lut_i = luts_[width_map[i]][group_pos[i]]
-        elif luts_ is not None:
-            lut_i = luts_[i] if jnp.ndim(luts_) == 3 else luts_
+        lut_i = row(luts_, i) if per_layer else luts_
+        if lut_i is not None and prepared:
+            lp = {**lp, "ffn": {**lp["ffn"], **row(prepared, i)}}
         x, nc = block(lp, x, cache, window=w, lut=lut_i)
         new_caches.append(nc)
     with jax.named_scope("head"):
@@ -408,6 +447,7 @@ def decode_step(
     #     mixed-width dict {bits: (n_group, side, side)} — side = 16
     #     (W4A4) or 256 (composed W8A8 tables)
     width_map: tuple[int, ...] | None = None,
+    prepared=None,
 ) -> tuple[jax.Array, list[Params]]:
     """One serving step: append token at ``pos``, return next-token logits.
 
@@ -433,9 +473,14 @@ def decode_step(
     adaptive serving runtime (:mod:`repro.serving`) hot-swaps plans
     between batches by passing a different stack to the same traced
     executable, which only works if tracing never baked the table in.
+
+    ``prepared``: optional :func:`prepare_mlp_weights` of ``params`` at
+    the stack's widths, laid out as ``luts`` and passed the same way, so
+    the step quantizes only the activations; without it each MLP matmul
+    quantizes its weight on every call, to the same result.
     """
     return _decode_layers(cfg, params, caches, tokens, luts, width_map,
-                          partial(_block_decode, cfg, pos=pos))
+                          partial(_block_decode, cfg, pos=pos), prepared)
 
 
 # ---------------------------------------------------------------------------
@@ -478,6 +523,7 @@ def decode_step_paged(
     *,
     luts: jax.Array | dict[int, jax.Array] | None = None,
     width_map: tuple[int, ...] | None = None,
+    prepared=None,
 ) -> tuple[jax.Array, list[Params]]:
     """One continuous-batching step: every *active* slot advances one
     token at its own position; inactive slots compute padding rows whose
@@ -487,14 +533,16 @@ def decode_step_paged(
     This is :func:`decode_step` with the batch-shared scalar ``pos``
     replaced by per-slot vectors and the dense global-attention caches
     replaced by page pools (:func:`init_paged_caches`); both run one layer
-    loop, so the LUT-stack contract is the same, and all shapes are fixed
-    by ``(max_slots, pages_per_slot, page_size)``, so requests joining and
-    leaving the running batch never retrace.
+    loop, so the LUT-stack and ``prepared`` contracts are the same, and all
+    shapes are fixed by ``(max_slots, pages_per_slot, page_size)``, so
+    requests joining and leaving the running batch never retrace.
 
     Named scopes label the step's operations for a profiler trace:
     ``attention`` (projections, the paged KV write and gather, the output
     projection), ``mlp`` (with ``quantize`` inside
-    :func:`repro.quant.approx_linear`) and ``head`` (final norm, logits)."""
+    :func:`repro.quant.approx_linear`: the activation side and the
+    correction, and the weight side too where no ``prepared`` is given)
+    and ``head`` (final norm, logits)."""
     return _decode_layers(cfg, params, caches, tokens, luts, width_map,
                           partial(_block_decode_paged, cfg, pos=pos,
-                                  tables=tables, active=active))
+                                  tables=tables, active=active), prepared)

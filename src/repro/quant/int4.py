@@ -14,9 +14,19 @@ regime uses ``c = 8`` with a 16x16 table, W8A8 uses ``c = 128`` with a
 composed 256x256 table.  :func:`approx_linear` infers the width from the
 table it is handed (shapes are static under jit, so width dispatch never
 retraces on a hot-swap at a fixed width).
+
+The weight side of that arithmetic — per-column codes, scales and code
+sums — is a pure function of the weight and the width, so a server makes
+it once: :func:`prepare_weight` returns a :class:`PreparedWeight` (codes
+stored at one byte each, which the LUT kernel reads as stored), and
+:func:`approx_linear` takes either that or the float weight, which it
+prepares inside the same trace.
 """
 
 from __future__ import annotations
+
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +61,39 @@ def dequantize(codes: jax.Array, scale: jax.Array,
     return (codes.astype(jnp.float32) - float(bias)) * scale
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["codes", "scale", "colsum"],
+                   meta_fields=["bits"])
+@dataclasses.dataclass(frozen=True)
+class PreparedWeight:
+    """A ``(..., K, N)`` weight as the LUT matmul consumes it at ``bits``:
+    biased codes in ``[0, 2**bits)`` stored as uint8 ``(..., K, N)``, the
+    per-column scales ``(..., 1, N)`` f32 and the per-column code sums
+    ``(..., 1, N)`` int32 of the bias correction.  A pytree, so a stack of
+    them rides through ``jax.jit`` as arguments and indexes per layer with
+    ``jax.tree.map``; ``bits`` is static."""
+
+    codes: jax.Array
+    scale: jax.Array
+    colsum: jax.Array
+    bits: int
+
+
+def prepare_weight(w: jax.Array, bits: int) -> PreparedWeight:
+    """The weight side of :func:`approx_linear` for a ``(..., K, N)``
+    float weight (per column of each ``(K, N)`` matrix), bit-identical to
+    what the per-call form computes.  Under one ``jax.jit`` the round,
+    clip and cast fuse, so no int32 copy of the codes is materialized."""
+    wq, sw = quantize_intb(w, bits, axis=-2)
+    return PreparedWeight(codes=wq.astype(jnp.uint8),
+                          scale=sw.astype(jnp.float32),
+                          colsum=wq.sum(axis=-2, keepdims=True),
+                          bits=bits)
+
+
 def approx_linear(
-    x: jax.Array,     # (..., K) float
-    w: jax.Array,     # (K, N) float
+    x: jax.Array,                    # (..., K) float
+    w: jax.Array | PreparedWeight,   # (K, N) float, or prepared
     lut: jax.Array,   # (side, side) int32 approximate product table
     *,
     backend: str = "auto",
@@ -63,9 +103,14 @@ def approx_linear(
     256x256 -> W8A8).
 
     Per-row activation scales, per-column weight scales (standard WbAb).
-    The quantization, the bias correction and the rescale sit under
-    ``jax.named_scope("quantize")``; the kernel call with its wrapper
-    work (padding, tile extraction, output conversion) under
+    ``w`` is either a :class:`PreparedWeight` at the table's width, made
+    once by the caller (the serving engines make one per MLP matrix when
+    they are built), or a float weight, which is prepared here, in the
+    same trace, on every call (the profiler, the full forward, training);
+    the arithmetic is the same either way.  The activation quantization,
+    any per-call weight preparation, the bias correction and the rescale
+    sit under ``jax.named_scope("quantize")``; the kernel call with its
+    wrapper work (padding, tile extraction, output conversion) under
     ``lut.w<bits>`` (``lut.w4``, ``lut.w8``).
     """
     spec = width_from_lut(lut)
@@ -74,16 +119,20 @@ def approx_linear(
     x2 = x.reshape(-1, K)
     with jax.named_scope("quantize"):
         xq, sx = quantize_intb(x2, spec.bits, axis=-1)    # (M, K), (M, 1)
-        wq, sw = quantize_intb(w, spec.bits, axis=0)      # (K, N), (1, N)
+        if not isinstance(w, PreparedWeight):
+            w = prepare_weight(w, spec.bits)
+    if w.bits != spec.bits:
+        raise ValueError(f"weight prepared at {w.bits} bits, table is "
+                         f"{spec.bits}-bit")
 
     with jax.named_scope(f"lut.w{spec.bits}"):
-        raw = ops.approx_matmul(xq, wq, lut,
+        raw = ops.approx_matmul(xq, w.codes, lut,
                                 backend=backend).astype(jnp.float32)
     with jax.named_scope("quantize"):
         # exact correction of the biased-unsigned decomposition
         c = float(spec.bias)
         sum_a = xq.sum(axis=1, keepdims=True).astype(jnp.float32)  # (M, 1)
-        sum_b = wq.sum(axis=0, keepdims=True).astype(jnp.float32)  # (1, N)
+        sum_b = w.colsum.astype(jnp.float32)                        # (1, N)
         corrected = raw - c * sum_a - c * sum_b + c * c * K
-        out = corrected * sx * sw
-    return out.reshape(*lead, w.shape[1]).astype(x.dtype)
+        out = corrected * sx * w.scale
+    return out.reshape(*lead, w.codes.shape[-1]).astype(x.dtype)

@@ -42,9 +42,11 @@ from ..kernels.approx_matmul import takes_hi_pass
 from ..library.qos import (LayerPlan, plan_layer_areas, refresh_plan,
                            stack_luts, validate_lut_stack)
 from ..models import decode_fn, decode_paged_fn, init_caches
+from ..models.lm import prepare_mlp_weights
 from ..obs.trace import current_tracer
 from ..obs.trace import event as trace_event
 from ..obs.trace import span as trace_span
+from ..quant.int4 import PreparedWeight
 from .controller import effective_load_ms
 from .loadgen import LoadProfile, Request, synth_requests
 from .telemetry import Telemetry
@@ -97,6 +99,15 @@ def _on_device(stack):
             if isinstance(stack, dict) else jnp.asarray(stack))
 
 
+def weight_codes(prepared) -> tuple[int, int]:
+    """How many MLP matrices ``prepared`` (:func:`prepare_mlp_weights`)
+    holds, and the bytes of its codes, scales and column sums."""
+    stacks = jax.tree.leaves(prepared,
+                             is_leaf=lambda a: isinstance(a, PreparedWeight))
+    return (sum(p.codes.shape[0] for p in stacks),
+            sum(a.nbytes for a in jax.tree.leaves(prepared)))
+
+
 def wide_lut_layers(stack) -> int:
     """How many layers of a LUT stack (or of each stack of a mixed-width
     dict) carry a table for which the LUT kernel runs its second pass."""
@@ -132,6 +143,7 @@ class ServingEngine:
         self.total = self.prompt_len + self.gen_len
         self._warmup = warmup_caches
         self._trace_count = 0
+        self._last_step_args = None   # what _step last passed the step
         self.last_tokens: np.ndarray | None = None   # (n_requests, gen_len)
         # the SLO health plane (obs.health.HealthPlane), bound by serve()/
         # start(); every control-plane trace event is mirrored into it so
@@ -192,6 +204,7 @@ class ServingEngine:
                     for b, a in exact_mixed_stacks(self._width_map).items()}
                 self.width = None
                 self.widths = tuple(sorted(set(self._width_map)))
+                widths = self._width_map
             else:
                 stack = stack_luts(plan, self._compiled)
                 self._luts = jnp.asarray(stack)
@@ -205,12 +218,20 @@ class ServingEngine:
                 self._exact_luts = jnp.asarray(np.broadcast_to(
                     exact_table("mul", self.width.bits).astype(np.int32),
                     (cfg.n_layers, side, side)).copy())
+                widths = self.width.bits
+            # the MLP weights' codes, scales and column sums depend only on
+            # the weights and each layer's width, which no plan swap
+            # changes: made once, passed to every step beside the tables
+            self._prepared = prepare_mlp_weights(params, widths)
+            n_codes, code_bytes = weight_codes(self._prepared)
             trace_event("serve.plan", plan=plan.plan_id,
                         lut_bits=self.lut_bits,
-                        wide_lut_layers=wide_lut_layers(stack))
+                        wide_lut_layers=wide_lut_layers(stack),
+                        weight_codes=n_codes, weight_code_bytes=code_bytes)
         else:
             self._luts = None
             self._exact_luts = None
+            self._prepared = None
             self.width = None
             self.widths = ()
 
@@ -218,10 +239,11 @@ class ServingEngine:
 
     def _make_step_fn(self):
         """Build the closure the engine jits exactly once:
-        ``step_fn(params, caches, *inputs, luts)`` runs the engine's
-        decode function on the inputs with the plan's LUT stack.  An
-        engine without a plan passes ``luts=None``, an empty pytree, so
-        its program holds no table argument."""
+        ``step_fn(params, caches, *inputs, luts, prepared)`` runs the
+        engine's decode function on the inputs with the plan's LUT stack
+        and the MLP weights prepared for it.  An engine without a plan
+        passes ``None`` for both, empty pytrees, so its program holds no
+        table or code argument."""
         step = self._decode_fn(self.cfg)
         cfg, wm = self.cfg, self._width_map
 
@@ -230,11 +252,11 @@ class ServingEngine:
             # compilations, not calls — the no-retrace-across-swaps
             # invariant is `trace_count == 1` after any number of swaps
             self._trace_count += 1
-            *inputs, luts = args
+            *inputs, luts, prepared = args
             if luts is None:   # the encoder-decoder step takes no tables
                 return step(cfg, params, caches, *inputs)
             return step(cfg, params, caches, *inputs, luts=luts,
-                        width_map=wm)
+                        width_map=wm, prepared=prepared)
         return step_fn
 
     # ----------------------------------------------------------------- state
@@ -254,9 +276,13 @@ class ServingEngine:
     def plan(self) -> LayerPlan | None:
         return self._plan
 
-    def _step(self, caches, tok, pos, luts=None):
-        return self._jit_step(self.params, caches, tok, pos,
-                              self._luts if luts is None else luts)
+    def _step(self, caches, *inputs, luts=None):
+        """The jitted step on ``inputs`` with ``luts`` (default: the live
+        stack) and the prepared MLP weights."""
+        self._last_step_args = (self.params, caches, *inputs,
+                                self._luts if luts is None else luts,
+                                self._prepared)
+        return self._jit_step(*self._last_step_args)
 
     # ------------------------------------------------------------------ swap
     def swap_plan(self, plan: LayerPlan, stack, *, reason: str = "manual",
@@ -597,9 +623,9 @@ class ServingEngine:
                         with trace_span("serve.shadow"):
                             ts = time.perf_counter()
                             shadow_caches = jax.tree.map(jnp.copy, caches)
-                            shadow_logits, _ = self._jit_step(
-                                self.params, shadow_caches, tok, jnp.int32(t),
-                                self._exact_luts)
+                            shadow_logits, _ = self._step(
+                                shadow_caches, tok, jnp.int32(t),
+                                luts=self._exact_luts)
                             shadow_logits.block_until_ready()
                             shadow_s = time.perf_counter() - ts
                     logits, caches = self._step(caches, tok, jnp.int32(t),
@@ -833,7 +859,6 @@ class ContinuousServingEngine(ServingEngine):
         super().__init__(cfg, params, batch=max_slots, prompt_len=prompt_len,
                          gen_len=gen_len, **kw)
         self._started = False
-        self._last_step_args = None   # what step_once last passed the step
 
     def lowered_step(self):
         """The decode step lowered (not compiled) at the types of the
@@ -1188,8 +1213,8 @@ class ContinuousServingEngine(ServingEngine):
             if want_shadow:
                 with trace_span("serve.shadow"):
                     shadow_caches = jax.tree.map(jnp.copy, self._caches)
-                    shadow_logits, _ = self._jit_step(
-                        self.params, shadow_caches, *jt, self._exact_luts)
+                    shadow_logits, _ = self._step(shadow_caches, *jt,
+                                                  luts=self._exact_luts)
                     shadow_logits.block_until_ready()
             t0 = time.perf_counter()
             with trace_span("serve.step.launch"):
@@ -1199,9 +1224,8 @@ class ContinuousServingEngine(ServingEngine):
                     # from a real one to the telemetry, the SLO monitors
                     # and the detectors
                     time.sleep(self.inject_step_delay)
-                self._last_step_args = (self.params, self._caches, *jt,
-                                        luts)
-                logits, self._caches = self._jit_step(*self._last_step_args)
+                logits, self._caches = self._step(self._caches, *jt,
+                                                  luts=luts)
             with trace_span("serve.step.wait"):
                 logits.block_until_ready()
             step_s = time.perf_counter() - t0
